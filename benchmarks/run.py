@@ -10,7 +10,8 @@
                         32-client buffered-async run (wall-clock speedup,
                         plus padding_waste / host_materializations stats)
   engine_sharded      — shard_map cohort split over 8 forced host devices
-                        vs single-device vmap, equality at cohort ≥ 32
+                        (or the accelerators present) vs single-device
+                        vmap, equality at cohort ≥ 32
   serve               — batched personalization through
                         PersonalizationServer vs per-request loop at 32
                         concurrent users (req/s, zero host materializations)
@@ -57,6 +58,7 @@ import numpy as np
 
 from benchmarks.common import (ALGOS, FAST, acc_at_time_budget, run_algo,
                                setup)
+from repro.launch.compile_cache import enable_compile_cache
 
 OUT_DIR = "experiments/bench"
 
@@ -237,38 +239,56 @@ def engine():
     return speedup
 
 
+def _delegate_to_cpu_child(name: str, *, cpu_devices: int,
+                           min_devices: int) -> bool:
+    """Device-count guard for the multi-device rows; True when the row ran
+    in a child process and its CSV rows were passed through.
+
+    On a CPU parent with fewer than ``cpu_devices`` devices the row
+    re-execs itself under ``--xla_force_host_platform_device_count`` (the
+    split must exist before JAX initializes).  On any other backend it
+    never falls back to the CPU: it runs on the devices present, or raises
+    when there are fewer than ``min_devices``.
+    """
+    n = jax.device_count()
+    backend = jax.default_backend()
+    if backend != "cpu":
+        if n < min_devices:
+            raise RuntimeError(f"{name} needs {min_devices} {backend} "
+                               f"devices; this host has {n}")
+        return False
+    if n >= cpu_devices:
+        return False
+    if os.environ.get("_BENCH_CPU_CHILD"):
+        raise RuntimeError(f"forced {cpu_devices}-device split did not "
+                           f"take effect (device_count={n})")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", _BENCH_CPU_CHILD="1")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + f" --xla_force_host_platform_device_count="
+                          f"{cpu_devices}").strip()
+    res = subprocess.run(
+        [sys.executable, "-m", "benchmarks.run", "--only", name],
+        env=env, capture_output=True, text=True)
+    rows = [line for line in res.stdout.splitlines()
+            if line.startswith(f"{name},")]
+    for line in rows:
+        print(line, flush=True)
+    if res.returncode != 0 or not rows:
+        sys.stderr.write(res.stderr[-4000:])
+        raise RuntimeError(f"{name} {cpu_devices}-device child failed")
+    return True
+
+
 def engine_sharded():
-    """8-virtual-device CPU shard_map cohort vs single-device vmap.
+    """shard_map cohort over every device vs single-device vmap.
 
     The acceptance row: the sharded path must complete a cohort ≥ 32 run
-    with deltas equal to the vmap path (atol ≤ 1e-5).  Needs the forced
-    host-device split BEFORE jax initializes, so when the parent process
-    sees < 8 devices it re-execs itself with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and passes the
-    child's engine_sharded rows through.
+    with deltas equal to the vmap path (atol ≤ 1e-5).  On a CPU parent it
+    runs on 8 forced host devices (see :func:`_delegate_to_cpu_child`); on
+    an accelerator it runs on the devices present (at least 2).
     """
-    if jax.device_count() < 8:
-        if os.environ.get("_ENGINE_SHARDED_CHILD"):
-            raise RuntimeError(
-                "forced 8-device split did not take effect "
-                f"(device_count={jax.device_count()})")
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8"
-                            ).strip()
-        env["_ENGINE_SHARDED_CHILD"] = "1"
-        res = subprocess.run(
-            [sys.executable, "-m", "benchmarks.run", "--only",
-             "engine_sharded"],
-            env=env, capture_output=True, text=True)
-        rows = [line for line in res.stdout.splitlines()
-                if line.startswith("engine_sharded,")]
-        for line in rows:
-            print(line, flush=True)
-        if res.returncode != 0 or not rows:
-            sys.stderr.write(res.stderr[-4000:])
-            raise RuntimeError("engine_sharded 8-device child failed")
+    if _delegate_to_cpu_child("engine_sharded", cpu_devices=8,
+                              min_devices=2):
         return
 
     from repro.core import PersAFLConfig
@@ -494,12 +514,15 @@ def serve_transport():
         psrv = make_server()
         ts = await TransportServer(psrv, flush_ms=100.0,
                                    max_inflight=4 * conns).start()
+        # the loadgen imports JAX (through the wire codec); pinned to the
+        # CPU so it never contends for this process's accelerator
         proc = await asyncio.create_subprocess_exec(
             sys.executable, "-m", "benchmarks.transport_loadgen",
             "--port", str(ts.port), "--conns", str(conns),
             "--rounds", str(rounds), "--reps", str(reps),
             "--d", str(d), "--rows", str(rows),
-            stdout=asyncio.subprocess.PIPE)
+            stdout=asyncio.subprocess.PIPE,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
         out, _ = await proc.communicate()
         if proc.returncode != 0:
             raise RuntimeError(
@@ -558,31 +581,10 @@ def serve_mesh():
         axis splits every stored row 4 ways and the 2-slice cohort axis
         buckets 4 users into 4 rows where the 8-slice 1-D mesh pads to 8.
 
-    Like ``engine_sharded``, needs the forced 8-device split before jax
-    initializes — re-execs itself when the parent sees < 8 devices.
+    Needs 8 devices: on a CPU parent, 8 forced host devices (see
+    :func:`_delegate_to_cpu_child`); on an accelerator, 8 chips.
     """
-    if jax.device_count() < 8:
-        if os.environ.get("_SERVE_MESH_CHILD"):
-            raise RuntimeError(
-                "forced 8-device split did not take effect "
-                f"(device_count={jax.device_count()})")
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8"
-                            ).strip()
-        env["_SERVE_MESH_CHILD"] = "1"
-        res = subprocess.run(
-            [sys.executable, "-m", "benchmarks.run", "--only",
-             "serve_mesh"],
-            env=env, capture_output=True, text=True)
-        rows = [line for line in res.stdout.splitlines()
-                if line.startswith("serve_mesh,")]
-        for line in rows:
-            print(line, flush=True)
-        if res.returncode != 0 or not rows:
-            sys.stderr.write(res.stderr[-4000:])
-            raise RuntimeError("serve_mesh 8-device child failed")
+    if _delegate_to_cpu_child("serve_mesh", cpu_devices=8, min_devices=8):
         return
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1095,7 +1097,7 @@ def kernels():
 
     w = jax.random.normal(ks[0], (1 << 20,))
     g = jax.random.normal(ks[1], (1 << 20,))
-    t_kern = timeit(lambda: FK.sgd_step(w, g, 0.01))
+    t_kern = timeit(lambda: FK.sgd_step(w, g, 0.01, interpret=True))
     t_ref = timeit(lambda: FR.sgd_step_ref(w, g, 0.01))
     print(f"kernel_fused_update,{t_kern:.0f},ref_us={t_ref:.0f}")
 
@@ -1134,6 +1136,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(BENCHES))
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     for name in names:

@@ -34,8 +34,7 @@ from repro.core.quant import QuantStack
 from repro.core.subset import merge_subset, subset_like
 from repro.kernels.fused_update.ops import (apply_delta_tree,
                                             apply_rows_q_tree,
-                                            apply_rows_tree, donate_argnums,
-                                            spans_devices)
+                                            apply_rows_tree, spans_devices)
 
 
 def init_server_state(params) -> ServerState:
@@ -56,7 +55,7 @@ def init_server_state(params) -> ServerState:
 
 @functools.lru_cache(maxsize=None)
 def _apply_update_jit():
-    @functools.partial(jax.jit, donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, donate_argnums=0)
     def apply(state, delta, beta, staleness, damping):
         staleness = jnp.asarray(staleness, jnp.int32)
         scale = jnp.asarray(beta, jnp.float32) \
@@ -85,7 +84,7 @@ def apply_update(state: ServerState, delta, beta: float, staleness,
 
 @functools.lru_cache(maxsize=None)
 def _apply_buffered_jit():
-    @functools.partial(jax.jit, donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, donate_argnums=0)
     def apply(state, delta_sum, count, beta, staleness_max, staleness_sum):
         count = jnp.asarray(count)
         scale = beta / jnp.maximum(count.astype(jnp.float32), 1.0)
@@ -122,7 +121,7 @@ def _apply_rows_state_jit(donate: bool):
     # (the serving ring must keep the pre-apply params alive as a window
     # snapshot, the simulators need not)
     @functools.partial(jax.jit, static_argnames=("mode",),
-                       donate_argnums=donate_argnums(0) if donate else ())
+                       donate_argnums=0 if donate else ())
     def apply(state, delta_stack, weights, order, count, staleness_max,
               staleness_sum, mode: str = "auto"):
         params = state.params
@@ -159,7 +158,7 @@ def _apply_rows_q_state_jit(donate: bool):
     # routes through the fused dequant×weight×accumulate kernel — an fp32
     # copy of the bank never exists, not even transiently inside the jit
     @functools.partial(jax.jit, static_argnames=("mode",),
-                       donate_argnums=donate_argnums(0) if donate else ())
+                       donate_argnums=0 if donate else ())
     def apply(state, q_stack, weights, order, count, staleness_max,
               staleness_sum, mode: str = "auto"):
         params = state.params

@@ -16,9 +16,9 @@ Architecture (DESIGN.md §2 extension):
     over every addressable device; see ``cohort_impl``.  Cohorts are padded
     up to power-of-two buckets so the jit cache stays O(log max_cohort)
     instead of one compile per cohort size.
-  * The stacked batch buffer is donated (``donate_argnums``) so XLA may
-    reuse its pages for the per-client delta stack — the cohort call is a
-    single device round-trip regardless of cohort size.
+  * The cohort call is a single device round-trip regardless of cohort
+    size.  (The stacked batch buffer is not donated: no output shares its
+    shape, so XLA could not reuse it and would only warn.)
   * Simulators defer per-client compute: batches are recorded at
     download-completion time and materialized lazily in one cohort call
     right before the next server apply.  Every delta is therefore computed
@@ -89,9 +89,7 @@ import numpy as np
 
 from repro.core import client_update, split_batches_for_option
 from repro.core.types import PersAFLConfig
-from repro.kernels.fused_update.ops import donate_argnums
-from repro.sharding.ctx import (active_mesh, cohort_axis_size, cohort_mesh,
-                                shard_map_compat)
+from repro.sharding.ctx import active_mesh, cohort_axis_size, cohort_mesh
 
 
 def _stack(batch_list: List):
@@ -210,14 +208,14 @@ class CohortEngine:
 
     ``mesh`` picks the layout for the shard_map path: the 1-D
     ``("cohort",)`` mesh (default), or a 2-D ``("cohort", "model")`` mesh
-    from :func:`repro.sharding.ctx.cohort_model_mesh` — the shard_map
-    body stays Manual over "cohort" ONLY (the in/out ``P("cohort")``
-    pytree prefixes describe just the manual axis), while the "model"
-    axis is left to the Auto partitioner: params constrained by
-    ``param_shardings`` (a params-shaped pytree of ``NamedSharding``s,
-    e.g. from :func:`repro.sharding.rules.param_shardings`) propagate
-    their model-axis placement through the per-row update, so the bank's
-    rows come back split along BOTH axes.  The masked cohort mean is one
+    from :func:`repro.sharding.ctx.cohort_model_mesh`.  The shard_map
+    body is Manual over every mesh axis, with the params gathered
+    replicated into it, so each row's update runs model-replicated; the
+    "model" axis shards storage: with ``param_shardings`` (a params-shaped
+    pytree of ``NamedSharding``s, e.g. from
+    :func:`repro.sharding.rules.param_shardings`) the delta stack is
+    re-sharded on the way out, so the bank's rows come back split along
+    BOTH axes.  The masked cohort mean is one
     ``psum("cohort")`` per leaf and never crosses "model" — a
     cross-model reduction would re-reduce within each row.  When no
     ``mesh`` is passed, the ambient :func:`repro.sharding.ctx.use_mesh`
@@ -283,7 +281,6 @@ class CohortEngine:
         self._ndev = 1
         self._jit_cohort_sum = None
         self._jit_cohort_s = None
-        donate = donate_argnums(1)
         if cohort_impl == "vmap":
             cohort_fn = lambda params, stacked: jax.vmap(  # noqa: E731
                 lambda b: _one(params, b))(stacked)
@@ -319,11 +316,11 @@ class CohortEngine:
             # reductions would otherwise reassociate with the model-axis
             # size and break bit-parity across mesh layouts.  (A partially-
             # Auto model axis would shard the compute too, but jax 0.4.x
-            # hard-crashes XLA on any scan under subgroup-manual spmd —
-            # and real archs scan everywhere.)  The model axis shards
-            # STORAGE: _bank_constrain re-shards the delta stack on the
-            # way out, and the server device_puts params/snapshots.
-            _all_axes = tuple(self._mesh.axis_names)
+            # crashed XLA on any scan under subgroup-manual spmd, and real
+            # archs scan everywhere; not re-tested on jax 0.9.0.)  The
+            # model axis shards STORAGE: _bank_constrain re-shards the
+            # delta stack on the way out, and the server device_puts
+            # params/snapshots.
 
             def _gather(params):
                 # explicit replicate of model-sharded params before the
@@ -366,15 +363,14 @@ class CohortEngine:
                 # out_specs is a pytree PREFIX: a bare P("cohort") covers
                 # whatever structure the strategy's delta takes — full
                 # params-shaped or a pruned personal_subset tree (which a
-                # params-shaped spec tree could not describe).  Only the
-                # manual "cohort" axis appears in the specs: the "model"
-                # axis (if the mesh has one) stays Auto.
-                out = shard_map_compat(
+                # params-shaped spec tree could not describe).  Axes the
+                # specs leave out (the "model" axis) see replicated data.
+                out = jax.shard_map(
                     _shard_body, mesh=self._mesh,
                     in_specs=(jax.tree.map(lambda _: P(), params),
                               jax.tree.map(lambda _: P("cohort"), stacked)),
                     out_specs=P("cohort"),
-                    manual_axes=_all_axes)(_gather(params), stacked)
+                    check_vma=False)(_gather(params), stacked)
                 return _bank_constrain(out)
 
             def _shard_body_s(params, stacked, cstates, shared):
@@ -387,12 +383,12 @@ class CohortEngine:
                 # state buffers is split on the cohort axis, params and the
                 # shared state replicated; outputs (delta stack, cstate
                 # stack) come back cohort-sharded
-                delta, cs = shard_map_compat(
+                delta, cs = jax.shard_map(
                     _shard_body_s, mesh=self._mesh,
                     in_specs=(P(), P("cohort"), P("cohort"), P()),
                     out_specs=(P("cohort"), P("cohort")),
-                    manual_axes=_all_axes)(_gather(params), stacked,
-                                           cstates, shared)
+                    check_vma=False)(_gather(params), stacked, cstates,
+                                     shared)
                 return _bank_constrain(delta), cs
 
             def _sum_body(params, stacked, mask):
@@ -408,26 +404,20 @@ class CohortEngine:
                                     local)
 
             def sum_fn(params, stacked, mask):
-                return shard_map_compat(
+                return jax.shard_map(
                     _sum_body, mesh=self._mesh,
                     in_specs=(jax.tree.map(lambda _: P(), params),
                               jax.tree.map(lambda _: P("cohort"), stacked),
                               P("cohort")),
                     out_specs=jax.tree.map(lambda _: P(), params),
-                    manual_axes=_all_axes)(_gather(params), stacked,
-                                           mask)
+                    check_vma=False)(_gather(params), stacked, mask)
 
-            self._jit_cohort_sum = jax.jit(sum_fn,
-                                           donate_argnums=donate)
+            self._jit_cohort_sum = jax.jit(sum_fn)
         else:
             raise ValueError(f"unknown cohort_impl {cohort_impl!r}")
-        self._jit_cohort = jax.jit(cohort_fn, donate_argnums=donate)
+        self._jit_cohort = jax.jit(cohort_fn)
         if self.stateful:
-            # the stacked batch buffer is still donated; the stacked
-            # cstate input is NOT — its rows alias the caller's per-client
-            # state trees only through a fresh stack, but post_round hooks
-            # may still read the old trees
-            self._jit_cohort_s = jax.jit(cohort_s_fn, donate_argnums=donate)
+            self._jit_cohort_s = jax.jit(cohort_s_fn)
 
     def add_bank_hook(self, fn: Callable[["DeltaBank"], None]) -> None:
         """Register a bank-handoff callback (serving ring retention, stats

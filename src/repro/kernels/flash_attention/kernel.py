@@ -68,11 +68,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         softcap: float = 0.0, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool):
     """q: (B,S,Hq,hd); k,v: (B,T,Hkv,hd) -> (B,S,Hq,hd).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (our
-    validation mode); on TPU pass ``interpret=False``.
+    ``interpret`` has no default: ``ops.flash_attention`` decides it
+    (``True`` runs the kernel body in Python on CPU).  The v5e compiler
+    refuses this kernel: the ``(1, block_q, 1, hd)`` block breaks the
+    last-two-dims tiling rule.
     """
     B, S, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]

@@ -22,7 +22,9 @@ Two stacked-bank apply kernels close every aggregation window:
     so one compile per bucket serves every window composition.
 
 Both have jnp oracles in ``ref.py`` (bit-comparable in interpret mode) and
-pytree-aware jitted fronts in ``ops.py``.
+pytree-aware jitted fronts in ``ops.py``.  Every entry takes ``interpret``
+with no default: ``ops.py`` is the one place that decides (the compiled
+kernel on a TPU, the Pallas interpreter elsewhere).
 """
 from __future__ import annotations
 
@@ -55,7 +57,7 @@ def _prox_outer_kernel(w_ref, t_ref, o_ref, *, eta, lam):
     o_ref[...] = (w - eta * lam * (w - t)).astype(o_ref.dtype)
 
 
-def _run_flat(kernel, out_dtype, *arrays, interpret=True):
+def _run_flat(kernel, out_dtype, *arrays, interpret: bool):
     """Pad to a BLOCK multiple, run the 1-D grid, unpad."""
     flat = [a.reshape(-1) for a in arrays]
     n = flat[0].shape[0]
@@ -75,19 +77,19 @@ def _run_flat(kernel, out_dtype, *arrays, interpret=True):
     return out[:n].reshape(arrays[0].shape)
 
 
-def sgd_step(w, g, eta: float, *, interpret: bool = True):
+def sgd_step(w, g, eta: float, *, interpret: bool):
     return _run_flat(functools.partial(_sgd_kernel, eta=eta), w.dtype, w, g,
                      interpret=interpret)
 
 
 def prox_inner(theta, g, w, eta_in: float, lam: float, *,
-               interpret: bool = True):
+               interpret: bool):
     return _run_flat(functools.partial(_prox_inner_kernel, eta_in=eta_in,
                                        lam=lam),
                      theta.dtype, theta, g, w, interpret=interpret)
 
 
-def prox_outer(w, theta, eta: float, lam: float, *, interpret: bool = True):
+def prox_outer(w, theta, eta: float, lam: float, *, interpret: bool):
     return _run_flat(functools.partial(_prox_outer_kernel, eta=eta, lam=lam),
                      w.dtype, w, theta, interpret=interpret)
 
@@ -126,7 +128,7 @@ def _apply_rows_kernel(w_ref, d_ref, s_ref, o_ref):
         o_ref[...] = (o_ref[...].astype(jnp.float32) - part).astype(o_ref.dtype)
 
 
-def apply_rows(w, d_stack, weights, *, interpret: bool = True):
+def apply_rows(w, d_stack, weights, *, interpret: bool):
     """Stacked server apply w ← w − Σ_i weights[i]·Δ_i, one fused pass.
 
     ``d_stack``: ``[M, *w.shape]`` stacked delta buffer (a DeltaBank's
@@ -183,7 +185,7 @@ def _apply_rows_q_kernel(w_ref, q_ref, s_ref, sc_ref, o_ref):
         o_ref[...] = (o_ref[...].astype(jnp.float32) - part).astype(o_ref.dtype)
 
 
-def apply_rows_q(w, q_stack, scales, weights, *, interpret: bool = True):
+def apply_rows_q(w, q_stack, scales, weights, *, interpret: bool):
     """Quantized stacked apply ``w ← w − Σ_i weights[i]·scales[i]·q_i``.
 
     ``q_stack``: ``[M, *w.shape]`` int8 rows (symmetric absmax quantized);
@@ -226,7 +228,7 @@ def apply_rows_q(w, q_stack, scales, weights, *, interpret: bool = True):
     return out[:n].reshape(w.shape)
 
 
-def apply_scaled(w, d, scale, *, interpret: bool = True):
+def apply_scaled(w, d, scale, *, interpret: bool):
     """Server apply w ← w − s·Δ in one read-modify-write pass."""
     flat_w, flat_d = w.reshape(-1), d.reshape(-1)
     n = flat_w.shape[0]

@@ -41,17 +41,9 @@ def prox_outer_tree(w_tree, theta_tree, eta: float, lam: float,
     return jax.tree.map(lambda w, t: fn(w, t, eta, lam), w_tree, theta_tree)
 
 
-def donate_argnums(*argnums):
-    """Donation is a no-op (plus a warning) off-TPU — only request it where
-    it buys the in-place apply.  Single policy point, resolved lazily so
-    merely importing the callers never initializes the JAX backend."""
-    return argnums if jax.default_backend() == "tpu" else ()
-
-
 @functools.lru_cache(maxsize=None)
 def _apply_delta_jit():
-    @functools.partial(jax.jit, static_argnames=("mode",),
-                       donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=0)
     def apply(w_tree, d_tree, scale, mode: str = "auto"):
         fn = _dispatch(K.apply_scaled, R.apply_scaled_ref, mode)
         s = jnp.asarray(scale, jnp.float32)
@@ -64,15 +56,15 @@ def apply_delta_tree(w_tree, d_tree, scale, mode: str = "auto"):
 
     ``scale`` is traced (β, β/M, or staleness-damped β/(1+τ)^a), so one
     compile serves every staleness value and buffer count; the params tree
-    is donated so on TPU the apply is an in-place read-modify-write.
+    is donated (on every backend), so the apply is an in-place
+    read-modify-write and the caller's input tree is deleted.
     """
     return _apply_delta_jit()(w_tree, d_tree, scale, mode=mode)
 
 
 @functools.lru_cache(maxsize=None)
 def _apply_rows_jit():
-    @functools.partial(jax.jit, static_argnames=("mode",),
-                       donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=0)
     def apply(w_tree, stack_tree, weights, mode: str = "auto"):
         fn = _dispatch(K.apply_rows, R.apply_rows_ref, mode)
         s = jnp.asarray(weights, jnp.float32)
@@ -99,7 +91,7 @@ def _apply_rows_seq_jit():
     # order-invariant sequential twin of _apply_rows_jit: the dispatch for
     # device-spanning stacks, where a per-shard partial-sum reduction
     # would make the flush result depend on the mesh layout
-    @functools.partial(jax.jit, donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, donate_argnums=0)
     def apply(w_tree, stack_tree, weights, order):
         s = jnp.asarray(weights, jnp.float32)
         return jax.tree.map(
@@ -110,7 +102,7 @@ def _apply_rows_seq_jit():
 
 @functools.lru_cache(maxsize=None)
 def _apply_rows_q_seq_jit():
-    @functools.partial(jax.jit, donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, donate_argnums=0)
     def apply(w_tree, q_tree, scales_tree, weights, order):
         s = jnp.asarray(weights, jnp.float32)
         return jax.tree.map(
@@ -127,8 +119,7 @@ def _default_order(stack_tree):
 
 @functools.lru_cache(maxsize=None)
 def _apply_rows_q_jit():
-    @functools.partial(jax.jit, static_argnames=("mode",),
-                       donate_argnums=donate_argnums(0))
+    @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=0)
     def apply(w_tree, q_tree, scales_tree, weights, mode: str = "auto"):
         fn = _dispatch(K.apply_rows_q, R.apply_rows_q_ref, mode)
         s = jnp.asarray(weights, jnp.float32)

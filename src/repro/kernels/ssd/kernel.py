@@ -10,6 +10,10 @@ attention-like form, which maps onto the MXU as (chunk × chunk) matmuls.
 Grid: (batch, n_chunks) — chunks innermost/sequential per batch row.
 Blocks: one chunk of x/dt/B/C per step; all heads resident (head_dim ≤ 64,
 state ≤ 128 keeps VMEM ≈ a few MB for the assigned configs).
+
+Status on TPU v5e: the compiler refuses this kernel (``jnp.cumsum`` has no
+Mosaic lowering), so it runs only interpreted.  ``interpret`` has no
+default; ``ops.ssd`` decides it.
 """
 from __future__ import annotations
 
@@ -71,7 +75,7 @@ def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, state_ref, *,
 
 
 def ssd_fwd(x, dt, a_log, B_mat, C_mat, *, chunk: int = 128,
-            interpret: bool = True):
+            interpret: bool):
     """x: (B,S,H,P); dt: (B,S,H); a_log: (H,); B/C: (B,S,G,N) -> (B,S,H,P) f32."""
     Bb, S, H, P = x.shape
     G, N = B_mat.shape[2], B_mat.shape[3]

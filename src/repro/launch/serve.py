@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, reduce_for_smoke
 from repro.core.types import PersAFLConfig
 from repro.data import synthetic_token_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 
 
@@ -207,7 +208,7 @@ def _serve_transport(args, server) -> None:
         pass
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -273,7 +274,63 @@ def main():
                          "its own --process-id)")
     ap.add_argument("--process-id", type=int, default=0,
                     help="this process's rank in [0, --num-processes)")
-    args = ap.parse_args()
+    return ap
+
+
+def personalization_config(args) -> PersAFLConfig:
+    """Mode B/C hyper-parameters from the CLI flags."""
+    return PersAFLConfig(option="C", lam=args.lam, alpha=args.alpha,
+                         inner_steps=args.inner_steps, inner_eta=0.01)
+
+
+def make_server(args, cfg, params):
+    """The PersonalizationServer the CLI flags describe, plus the resolved
+    personal subset (None for full-model personalization)."""
+    from repro.core.subset import SubsetSpec
+    from repro.serving import PersonalizationServer
+    loss = lambda p, b: api.loss_fn(cfg, p, b)              # noqa: E731
+    pcfg = personalization_config(args)
+    subset_spec = SubsetSpec.resolve(args.personal_subset, params)
+    mesh_kw = {}
+    if args.model_axis is not None:
+        from repro.sharding.ctx import cohort_model_mesh
+        from repro.sharding.rules import param_shardings
+        mesh = cohort_model_mesh(args.model_axis)
+        mesh_kw = {"cohort_impl": "shard_map", "mesh": mesh,
+                   "param_shardings": param_shardings(cfg, params, mesh)}
+        print(f"2-D mesh: cohort={mesh.devices.shape[0]} × "
+              f"model={mesh.devices.shape[1]} over "
+              f"{mesh.devices.size} devices", flush=True)
+    server = PersonalizationServer(params, loss, pcfg, modes=(args.mode,),
+                                   max_pending=max(args.requests, 1),
+                                   personal_subset=subset_spec,
+                                   delta_dtype=args.delta_dtype, **mesh_kw)
+    return server, subset_spec
+
+
+def personalize(args, cfg, server):
+    """Submit one personalization stream per user (``--requests`` users)
+    and flush them as one cohort; returns the user ids in order."""
+    plen = _personalize_len(cfg, args.personalize_len
+                            if args.personalize_len is not None
+                            else args.prompt_len)
+    tickets = [server.submit(f"user{u}",
+                             _user_batch(cfg, args.seed + u, plen),
+                             mode=args.mode)
+               for u in range(args.requests)]
+    server.flush()
+    stats = server.stats
+    print(f"personalized {args.requests} users through "
+          f"PersonalizationServer (mode {args.mode}, len={plen}, "
+          f"cohort_calls={stats['cohort_calls']}, "
+          f"host_materializations={stats['host_materializations']})",
+          flush=True)
+    return [t.user for t in tickets]
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     if args.listen is not None:
         args.personalize = True
@@ -303,47 +360,12 @@ def main():
     server_stats = None
     subset_spec = None
     if args.personalize:
-        from repro.core.subset import SubsetSpec
-        from repro.serving import PersonalizationServer
-        plen = _personalize_len(cfg, args.personalize_len
-                                if args.personalize_len is not None
-                                else args.prompt_len)
-        loss = lambda p, b: api.loss_fn(cfg, p, b)          # noqa: E731
-        pcfg = PersAFLConfig(option="C", lam=args.lam, alpha=args.alpha,
-                             inner_steps=args.inner_steps, inner_eta=0.01)
-        subset_spec = SubsetSpec.resolve(args.personal_subset, params)
-        mesh_kw = {}
-        if args.model_axis is not None:
-            from repro.sharding.ctx import cohort_model_mesh
-            from repro.sharding.rules import param_shardings
-            mesh = cohort_model_mesh(args.model_axis)
-            mesh_kw = {"cohort_impl": "shard_map", "mesh": mesh,
-                       "param_shardings":
-                           param_shardings(cfg, params, mesh)}
-            print(f"2-D mesh: cohort={mesh.devices.shape[0]} × "
-                  f"model={mesh.devices.shape[1]} over "
-                  f"{mesh.devices.size} devices", flush=True)
-        server = PersonalizationServer(params, loss, pcfg,
-                                       modes=(args.mode,),
-                                       max_pending=max(B, 1),
-                                       personal_subset=subset_spec,
-                                       delta_dtype=args.delta_dtype,
-                                       **mesh_kw)
+        server, subset_spec = make_server(args, cfg, params)
         if args.listen is not None:
             _serve_transport(args, server)
             return
-        tickets = [server.submit(f"user{u}",
-                                 _user_batch(cfg, args.seed + u, plen),
-                                 mode=args.mode)
-                   for u in range(B)]
-        server.flush()
-        heads = server.stacked_heads([t.user for t in tickets])
+        heads = server.stacked_heads(personalize(args, cfg, server))
         server_stats = server.stats
-        print(f"personalized {B} users through PersonalizationServer "
-              f"(mode {args.mode}, len={plen}, "
-              f"cohort_calls={server_stats['cohort_calls']}, "
-              f"host_materializations="
-              f"{server_stats['host_materializations']})")
 
     t0 = time.time()
     if heads is not None:

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, InputShape
 from repro.core import PersAFLConfig, client_update
 from repro.models import api
-from repro.sharding.ctx import shard_map_compat as _shard_map
 
 
 def microbatched(loss_fn: Callable, n_mb: int) -> Callable:
@@ -128,7 +127,7 @@ def make_cohort_train_step(cfg: ArchConfig, pcfg: PersAFLConfig, mesh,
     def train_step(server_params, stale_params, batch):
         batch_spec = jax.tree.map(
             lambda _: P(d_axes if len(d_axes) > 1 else d_axes[0]), batch)
-        return _shard_map(
+        return jax.shard_map(
             _local_round,
             mesh=mesh,
             in_specs=(specs_like(server_params, P()),
@@ -138,7 +137,7 @@ def make_cohort_train_step(cfg: ArchConfig, pcfg: PersAFLConfig, mesh,
                         "nu_mean": P()}),
             # manual only over the cohort axes — the model axis stays Auto,
             # so tensor parallelism keeps working INSIDE each cohort member
-            manual_axes=d_axes,
+            axis_names=frozenset(d_axes), check_vma=False,
         )(server_params, stale_params, batch)
 
     return train_step

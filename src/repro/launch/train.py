@@ -3,16 +3,19 @@
 Two modes:
   * ``--preset paper-mnist|paper-cifar`` — the paper's §5 experiment:
     asynchronous personalized FL over n heterogeneous clients with the
-    paper's CNNs, driven by the ``repro.fl.api.FLRun`` event loop with the
-    paper-faithful ``immediate()`` apply schedule (the end-to-end example;
-    a few hundred server rounds on CPU).
+    paper's CNNs, driven by the ``repro.fl.api.FLRun`` event loop.  The
+    apply schedule is ``--schedule`` (default: the paper-faithful
+    ``immediate``; ``buffered(M)`` closes FedBuff-style windows of M
+    deltas through the cohort engine and the fused ``apply_rows`` pass).
   * ``--arch <id> [--smoke]`` — PersA-FL over an assigned LLM architecture
-    (reduced config on CPU with --smoke; full config is what the dry-run
-    lowers for the production mesh).
+    (reduced config on CPU with --smoke; the full config runs on one TPU
+    v5e chip, see ``chip_smoke.py``).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --preset paper-mnist \
       --option C --rounds 200
+  PYTHONPATH=src python -m repro.launch.train --preset paper-mnist \
+      --schedule 'buffered(4)' --rounds 40
   PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --smoke \
       --rounds 4
 """
@@ -32,9 +35,16 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.configs.paper_models import CIFAR_CNN, MNIST_CNN
 from repro.core import PersAFLConfig
 from repro.data import make_federated_dataset, synthetic_token_batch
-from repro.fl import DelayModel, FLRun, immediate, make_personalized_eval
+from repro.fl import DelayModel, FLRun, make_personalized_eval
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
+from repro.tune.space import parse_schedule
+
+
+def all_finite(tree) -> bool:
+    """True when every leaf of ``tree`` is finite (a device reduction)."""
+    return all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(tree))
 
 
 def run_paper_preset(args) -> dict:
@@ -47,7 +57,7 @@ def run_paper_preset(args) -> dict:
     loss = lambda p, b: cnn_loss(ccfg, p, b, train=False)
     acc = lambda p, b: cnn_accuracy(ccfg, p, b)
     ev = make_personalized_eval(loss, acc, clients, ft_steps=1,
-                                ft_lr=args.eta)
+                                ft_lr=args.eta, with_loss=True)
     pcfg = PersAFLConfig(option=args.option, q_local=args.q, eta=args.eta,
                          beta=args.beta, alpha=args.alpha, lam=args.lam,
                          inner_steps=args.inner_steps,
@@ -55,19 +65,21 @@ def run_paper_preset(args) -> dict:
     sim = FLRun(clients=clients, loss_fn=loss, init_params=params,
                 pcfg=pcfg, delays=DelayModel(args.clients, seed=args.seed,
                                              scale=args.delay_scale),
-                strategy="persafl", schedule=immediate(),
+                strategy="persafl",
+                schedule=parse_schedule(args.schedule),
                 batch_size=args.batch, seed=args.seed)
     t0 = time.time()
     hist = sim.run(max_rounds=args.rounds,
-                   eval_every=args.eval_every, eval_fn=ev)
+                   eval_every=args.eval_every, eval_fn=ev, final_eval=True)
     wall = time.time() - t0
     out = {
         "preset": args.preset, "option": args.option, "rounds": args.rounds,
-        "acc": hist.acc, "times": hist.times, "rounds_series": hist.rounds,
+        "schedule": args.schedule, "acc": hist.acc, "loss": hist.loss,
+        "times": hist.times, "rounds_series": hist.rounds,
         "mean_active_ratio": float(np.mean(hist.active_ratio)),
         "staleness_max": int(max(hist.staleness)) if hist.staleness else 0,
         "staleness_mean": float(np.mean(hist.staleness)) if hist.staleness else 0,
-        "wall_s": wall,
+        "wall_s": wall, "params_finite": all_finite(sim.params),
     }
     os.makedirs(args.out, exist_ok=True)
     ckpt = os.path.join(args.out, f"{args.preset}_opt{args.option}")
@@ -109,14 +121,14 @@ def run_arch(args) -> dict:
         print(f"round {r}: loss={losses[-1]:.4f} "
               f"delta_norm={float(metrics['delta_norm']):.4f}", flush=True)
     out = {"arch": cfg.arch_id, "losses": losses,
-           "wall_s": time.time() - t0}
+           "wall_s": time.time() - t0, "params_finite": all_finite(params)}
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, f"train_{cfg.arch_id}.json"), "w") as f:
         json.dump(out, f, indent=2)
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default=None,
                     choices=[None, "paper-mnist", "paper-cifar"])
@@ -137,8 +149,17 @@ def main():
     ap.add_argument("--delay-scale", type=float, default=1.0)
     ap.add_argument("--eval-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--schedule", default="immediate",
+                    help="--preset apply schedule: 'immediate', "
+                         "'buffered(M)' or 'sync(m)'")
     ap.add_argument("--out", default="experiments/train")
+    return ap
+
+
+def main():
+    ap = build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
     if args.preset:
         run_paper_preset(args)
     elif args.arch:

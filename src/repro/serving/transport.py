@@ -385,7 +385,6 @@ class TransportServer:
         self._flush_handle = self._window_handle = None
         if self._srv is not None:
             self._srv.close()
-            await self._srv.wait_closed()
         for w in list(self._writers):
             w.close()
         # a handler parked in a long POLL wait is not woken by its writer
@@ -395,6 +394,10 @@ class TransportServer:
         if self._tasks:
             await asyncio.gather(*list(self._tasks),
                                  return_exceptions=True)
+        if self._srv is not None:
+            # last: on Python >= 3.12 wait_closed also waits for every
+            # open connection, so it would hang on a parked handler
+            await self._srv.wait_closed()
 
     async def serve_forever(self, *, announce: bool = False) -> None:
         if self._srv is None:

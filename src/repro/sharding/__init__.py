@@ -1,2 +1,2 @@
 from repro.sharding.ctx import (activation_sharding,   # noqa: F401
-                                shard_activation, shard_map_compat)
+                                shard_activation)

@@ -1,4 +1,4 @@
-"""Mesh context + activation sharding + version-portable shard_map.
+"""Mesh context + activation sharding.
 
 This module owns how every tensor in the system is *placed*:
 
@@ -24,10 +24,11 @@ This module owns how every tensor in the system is *placed*:
         ``P("cohort", *param_spec)`` per leaf right after — a pure
         placement move, bits unchanged.  Two reasons compute stays
         model-replicated: (a) ``lax.scan``/``lax.map`` inside a
-        partially-Auto shard_map hard-crashes XLA on the pinned jax 0.4.x
-        (``IsManualSubgroup`` check), and real archs scan internally;
-        (b) model-sharded grads reassociate cross-class reductions
-        (softmax) and break the bit-parity contract between mesh layouts.
+        partially-Auto shard_map crashed XLA on jax 0.4.x
+        (``IsManualSubgroup`` check), and real archs scan internally; that
+        crash has not been re-tested on jax 0.9.0; (b) model-sharded grads
+        reassociate cross-class reductions (softmax) and break the
+        bit-parity contract between mesh layouts.
         The masked cohort mean stays a single ``psum("cohort")`` per leaf
         that never crosses "model" (a cross-model reduction would
         re-reduce *within* each row — wrong math, not just wrong layout).
@@ -49,13 +50,6 @@ This module owns how every tensor in the system is *placed*:
     "attn_out", "moe_dispatch", ...) to ``PartitionSpec``s.  When no
     context is installed (unit tests, CPU smoke), ``shard_activation`` is
     a no-op, keeping the model code pure.
-
-:func:`shard_map_compat` is the single jax-version shim for manual-axes
-shard_map, shared by ``launch/steps.py`` (cohort train step) and
-``fl/engine.py`` (``cohort_impl="shard_map"``) — keep exactly one copy.
-The engine passes ``manual_axes=mesh.axis_names`` (full-Manual; see
-above); partial-Manual callers leave the remaining axes to the Auto
-partitioner on both jax spellings.
 """
 from __future__ import annotations
 
@@ -66,28 +60,6 @@ from typing import Dict, Optional, Tuple
 import jax
 
 _state = threading.local()
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map, Manual only over ``manual_axes``.
-
-    Newer jax exposes ``jax.shard_map(axis_names=..., check_vma=...)``;
-    0.4.x spells it ``jax.experimental.shard_map.shard_map(auto=...,
-    check_rep=...)`` with the complement axis set.  Mesh axes NOT in
-    ``manual_axes`` (the 2-D mesh's "model" axis) stay Auto: in/out specs
-    only describe the manual axes and XLA SPMD carries the rest, which is
-    how a bare ``P("cohort")`` prefix keeps working unchanged on the
-    ``("cohort", "model")`` mesh.
-    """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - manual
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
 
 
 # -- memoized mesh construction ---------------------------------------------

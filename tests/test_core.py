@@ -165,7 +165,8 @@ def test_apply_buffered_rows_matches_summed_apply():
     s_rows = apply_buffered_rows(init_server_state(params), stack, weights,
                                  jnp.asarray(2), staleness_max=2,
                                  staleness_sum=3.0)
-    s_ref = apply_buffered(init_server_state(params),
+    # the row apply donated ``params``: the reference starts from fresh zeros
+    s_ref = apply_buffered(init_server_state({"w": jnp.zeros(4)}),
                            {"w": jnp.ones(4) + 3 * jnp.ones(4)},
                            jnp.asarray(2), beta=1.0, staleness_max=2,
                            staleness_sum=3.0)

@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle,
 sweeping shapes and dtypes (assignment requirement)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -133,7 +135,7 @@ def test_fused_sgd_step(shape, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     w = jax.random.normal(ks[0], shape, dtype)
     g = jax.random.normal(ks[1], shape, dtype)
-    out = FK.sgd_step(w, g, 0.01)
+    out = FK.sgd_step(w, g, 0.01, interpret=True)
     ref = FR.sgd_step_ref(w, g, 0.01)
     assert out.dtype == w.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -146,10 +148,10 @@ def test_fused_prox_chain_matches_ref():
     th = jax.random.normal(ks[1], (511,))
     g = jax.random.normal(ks[2], (511,))
     np.testing.assert_allclose(
-        np.asarray(FK.prox_inner(th, g, w, 0.02, 20.0)),
+        np.asarray(FK.prox_inner(th, g, w, 0.02, 20.0, interpret=True)),
         np.asarray(FR.prox_inner_ref(th, g, w, 0.02, 20.0)), atol=1e-6)
     np.testing.assert_allclose(
-        np.asarray(FK.prox_outer(w, th, 0.01, 20.0)),
+        np.asarray(FK.prox_outer(w, th, 0.01, 20.0, interpret=True)),
         np.asarray(FR.prox_outer_ref(w, th, 0.01, 20.0)), atol=1e-6)
 
 
@@ -169,14 +171,14 @@ def test_fused_apply_scaled(shape, dtype):
     ks = jax.random.split(jax.random.PRNGKey(2), 2)
     w = jax.random.normal(ks[0], shape, dtype)
     d = jax.random.normal(ks[1], shape, dtype)
-    out = FK.apply_scaled(w, d, 0.37)
+    out = FK.apply_scaled(w, d, 0.37, interpret=True)
     ref = FR.apply_scaled_ref(w, d, 0.37)
     assert out.dtype == w.dtype
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
     # the scale must stay traced — one compile serves every staleness value
-    jit_apply = jax.jit(FK.apply_scaled)
+    jit_apply = jax.jit(functools.partial(FK.apply_scaled, interpret=True))
     out2 = jit_apply(w, d, jnp.float32(1.8))
     ref2 = FR.apply_scaled_ref(w, d, 1.8)
     np.testing.assert_allclose(np.asarray(out2, np.float32),
@@ -203,7 +205,7 @@ def test_fused_apply_rows_matches_ref(m, shape):
     w = jax.random.normal(ks[0], shape)
     d = jax.random.normal(ks[1], (m,) + shape)
     s = jax.random.normal(ks[2], (m,))
-    out = FK.apply_rows(w, d, s)
+    out = FK.apply_rows(w, d, s, interpret=True)
     ref = FR.apply_rows_ref(w, d, s)
     assert out.dtype == w.dtype
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -217,7 +219,7 @@ def test_fused_apply_rows_dtypes_and_traced_weights(dtype):
     d = jax.random.normal(ks[1], (8, 513), dtype)
     s = jax.random.normal(ks[2], (8,))
     # weights must stay traced: one compile serves every flush composition
-    out = jax.jit(FK.apply_rows)(w, d, s)
+    out = jax.jit(functools.partial(FK.apply_rows, interpret=True))(w, d, s)
     ref = FR.apply_rows_ref(w, d, s)
     assert out.dtype == dtype
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
@@ -232,7 +234,8 @@ def test_fused_apply_rows_masked_padding_rows_are_inert():
     d = jnp.stack([jnp.full((257,), 2.0),
                    jnp.full((257,), 123.0),   # padding rows: huge values
                    jnp.full((257,), -999.0)])
-    out = FK.apply_rows(w, d, jnp.asarray([0.5, 0.0, 0.0]))
+    out = FK.apply_rows(w, d, jnp.asarray([0.5, 0.0, 0.0]),
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
     ref = FR.apply_rows_ref(w, d, jnp.asarray([0.5, 0.0, 0.0]))
     np.testing.assert_allclose(np.asarray(ref), 0.0, atol=1e-6)
@@ -247,7 +250,8 @@ def test_apply_rows_tree_matches_per_row_applies():
     stack = jax.tree.map(
         lambda x: jax.random.normal(ks[2], (4,) + x.shape), tree)
     weights = jnp.asarray([0.1, 0.0, 0.3, 0.2])
-    fused = ops.apply_rows_tree(tree, stack, weights)
+    # the apply donates its params tree: hand it a copy, keep ``tree``
+    fused = ops.apply_rows_tree(jax.tree.map(jnp.copy, tree), stack, weights)
     seq = tree
     for i, wgt in enumerate(np.asarray(weights)):
         row = jax.tree.map(lambda x: x[i], stack)

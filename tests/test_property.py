@@ -240,7 +240,7 @@ def robust_case(draw):
     k = draw(st.integers(1, cap))
     seed = draw(st.integers(0, 2 ** 16))
     rng = np.random.RandomState(seed)
-    norms = np.abs(rng.randn(cap)) * 10 ** rng.randint(-1, 3, cap)
+    norms = np.abs(rng.randn(cap)) * 10.0 ** rng.randint(-1, 3, cap)
     bad = rng.rand(cap) < 0.2
     norms[bad] = rng.choice([np.nan, np.inf], bad.sum())
     idxs = rng.choice(cap, k, replace=False)

@@ -106,8 +106,11 @@ def test_apply_rows_q_tree_modes_agree():
                               .astype(np.float32)), w)
     qs = quantize_stack(stack)
     weights = jnp.asarray(rng.rand(3).astype(np.float32))
-    got_k = apply_rows_q_tree(w, qs.q, qs.scales, weights, mode="kernel")
-    got_r = apply_rows_q_tree(w, qs.q, qs.scales, weights, mode="ref")
+    # the apply donates its params tree: each mode gets its own copy
+    got_k = apply_rows_q_tree(jax.tree.map(jnp.copy, w), qs.q, qs.scales,
+                              weights, mode="kernel")
+    got_r = apply_rows_q_tree(jax.tree.map(jnp.copy, w), qs.q, qs.scales,
+                              weights, mode="ref")
     for a, b in zip(jax.tree.leaves(got_k), jax.tree.leaves(got_r)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 

@@ -75,7 +75,9 @@ def test_shard_map_bank_feeds_apply_rows():
     bank = engine.update_cohort(params, batch_list)
     weights = np.zeros(bank.capacity, np.float32)
     weights[:8] = 1.0 / 8
-    out = apply_rows_tree(params, bank.stacked, weights)
+    # the apply donates its params tree: hand it a copy, keep ``params``
+    out = apply_rows_tree(jax.tree.map(jnp.copy, params), bank.stacked,
+                          weights)
     rows = list(CohortEngine(_pcfg("A"), quad_loss,
                              cohort_impl="vmap").update_cohort(params,
                                                                batch_list))
