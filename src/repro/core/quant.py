@@ -38,7 +38,6 @@ fused dequant-×-weight-×-accumulate kernel
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
@@ -92,58 +91,38 @@ def _dequantize_stack(qstack: QuantStack):
         qstack.q, qstack.scales)
 
 
-@functools.lru_cache(maxsize=None)
-def _quantize_stack_jit():
-    return jax.jit(_quantize_stack)
-
-
-@functools.lru_cache(maxsize=None)
-def _dequantize_stack_jit():
-    return jax.jit(_dequantize_stack)
+_quantize_stack_jit = jax.jit(_quantize_stack)
+_dequantize_stack_jit = jax.jit(_dequantize_stack)
 
 
 def quantize_stack(tree) -> QuantStack:
     """``[capacity, ...]`` fp stacked pytree → :class:`QuantStack`."""
-    return _quantize_stack_jit()(tree)
+    return _quantize_stack_jit(tree)
 
 
 def dequantize_stack(qstack: QuantStack):
     """:class:`QuantStack` → fp32 stacked pytree (scale·q per row)."""
-    return _dequantize_stack_jit()(qstack)
+    return _dequantize_stack_jit(qstack)
 
 
-@functools.lru_cache(maxsize=None)
-def _quantize_tree_jit():
-    @jax.jit
-    def f(tree):
-        leaves, treedef = jax.tree.flatten(tree)
-        qs, scs = [], []
-        for x in leaves:
-            sc = jnp.max(jnp.abs(x.astype(jnp.float32))) / 127.0
-            qs.append(_q(x.astype(jnp.float32), sc))
-            scs.append(sc)
-        return QuantTree(jax.tree.unflatten(treedef, qs),
-                         jax.tree.unflatten(treedef, scs))
-    return f
-
-
-@functools.lru_cache(maxsize=None)
-def _dequantize_tree_jit():
-    @jax.jit
-    def f(qtree):
-        return jax.tree.map(lambda q, s: q.astype(jnp.float32) * s,
-                            qtree.q, qtree.scales)
-    return f
-
-
+@jax.jit
 def quantize_tree(tree) -> QuantTree:
     """Params(-subset) pytree → :class:`QuantTree` (per-leaf scalar scale).
     Used for retained ring snapshots of already-closed windows."""
-    return _quantize_tree_jit()(tree)
+    leaves, treedef = jax.tree.flatten(tree)
+    qs, scs = [], []
+    for x in leaves:
+        sc = jnp.max(jnp.abs(x.astype(jnp.float32))) / 127.0
+        qs.append(_q(x.astype(jnp.float32), sc))
+        scs.append(sc)
+    return QuantTree(jax.tree.unflatten(treedef, qs),
+                     jax.tree.unflatten(treedef, scs))
 
 
+@jax.jit
 def dequantize_tree(qtree: QuantTree):
-    return _dequantize_tree_jit()(qtree)
+    return jax.tree.map(lambda q, s: q.astype(jnp.float32) * s,
+                        qtree.q, qtree.scales)
 
 
 # -- error feedback ---------------------------------------------------------
@@ -155,18 +134,15 @@ def _ef_body(adj):
     return qstack, _quantize_stack(err)
 
 
-@functools.lru_cache(maxsize=None)
-def _ef_jit():
-    return jax.jit(_ef_body)
+@jax.jit
+def ef_quantize(raw):
+    return _ef_body(raw)
 
 
-@functools.lru_cache(maxsize=None)
-def _ef_res_jit():
-    @jax.jit
-    def f(raw, res):
-        return _ef_body(jax.tree.map(
-            lambda x, r: x.astype(jnp.float32) + r, raw, res))
-    return f
+@jax.jit
+def ef_quantize_res(raw, res):
+    return _ef_body(jax.tree.map(
+        lambda x, r: x.astype(jnp.float32) + r, raw, res))
 
 
 def ef_quantize_stack(raw, residual=None):
@@ -182,33 +158,29 @@ def ef_quantize_stack(raw, residual=None):
     tensor), which the EF property test bounds.
     """
     if residual is None:
-        return _ef_jit()(raw)
-    return _ef_res_jit()(raw, residual)
+        return ef_quantize(raw)
+    return ef_quantize_res(raw, residual)
 
 
 # -- gathers ----------------------------------------------------------------
 
-@functools.lru_cache(maxsize=None)
-def _gather_rows_jit():
-    @jax.jit
-    def f(qstack, rows):
-        return jax.tree.map(
-            lambda q, s: jnp.take(q, rows, axis=0).astype(jnp.float32)
-            * _bcast(jnp.take(s, rows, axis=0), q.ndim),
-            qstack.q, qstack.scales)
-    return f
+@jax.jit
+def gather_rows(qstack: QuantStack, rows):
+    """Dequantized fp32 ``[len(rows), ...]`` rows of a stack."""
+    return jax.tree.map(
+        lambda q, s: jnp.take(q, rows, axis=0).astype(jnp.float32)
+        * _bcast(jnp.take(s, rows, axis=0), q.ndim),
+        qstack.q, qstack.scales)
 
 
-@functools.lru_cache(maxsize=None)
-def _head_rows_jit():
-    @jax.jit
-    def f(snap, qstack, rows):
-        def one(p, q, s):
-            d = jnp.take(q, rows, axis=0).astype(jnp.float32) \
-                * _bcast(jnp.take(s, rows, axis=0), q.ndim)
-            return (p[None].astype(jnp.float32) - d).astype(p.dtype)
-        return jax.tree.map(one, snap, qstack.q, qstack.scales)
-    return f
+@jax.jit
+def head_rows(snap, qstack: QuantStack, rows):
+    """Head rows ``snapshot − scale·q``: f32 compute, param dtype out."""
+    def one(p, q, s):
+        d = jnp.take(q, rows, axis=0).astype(jnp.float32) \
+            * _bcast(jnp.take(s, rows, axis=0), q.ndim)
+        return (p[None].astype(jnp.float32) - d).astype(p.dtype)
+    return jax.tree.map(one, snap, qstack.q, qstack.scales)
 
 
 class QuantizedBank:
@@ -236,8 +208,7 @@ class QuantizedBank:
 
     def rows(self, rows):
         """Dequantized fp32 ``[len(rows), ...]`` gather (device-side)."""
-        return _gather_rows_jit()(self.stacked,
-                                  jnp.asarray(rows, jnp.int32))
+        return gather_rows(self.stacked, jnp.asarray(rows, jnp.int32))
 
     def row(self, i: int):
         return jax.tree.map(lambda x: x[0], self.rows([int(i)]))
@@ -264,8 +235,8 @@ class QuantizedHeads:
         return self.qbank.k
 
     def rows(self, rows):
-        return _head_rows_jit()(self.snapshot, self.qbank.stacked,
-                                jnp.asarray(rows, jnp.int32))
+        return head_rows(self.snapshot, self.qbank.stacked,
+                         jnp.asarray(rows, jnp.int32))
 
     def row(self, i: int):
         return jax.tree.map(lambda x: x[0], self.rows([int(i)]))

@@ -90,6 +90,7 @@ import numpy as np
 from repro.core import client_update, split_batches_for_option
 from repro.core.types import PersAFLConfig
 from repro.sharding.ctx import active_mesh, cohort_axis_size, cohort_mesh
+from repro.spans import span
 
 
 def _stack(batch_list: List):
@@ -448,8 +449,9 @@ class CohortEngine:
         self.stats["clients"] += k
         self.stats["max_cohort"] = max(self.stats["max_cohort"], k)
         self.stats["padding_waste"] += bucket - k
-        padded = list(batch_list) + [batch_list[-1]] * (bucket - k)
-        return _stack(padded), k, bucket
+        with span("engine.stack", rows=k, bucket=bucket):
+            padded = list(batch_list) + [batch_list[-1]] * (bucket - k)
+            return _stack(padded), k, bucket
 
     def update_cohort(self, params, batch_list: List,
                       cstate_list: Optional[List] = None) -> DeltaBank:
@@ -492,14 +494,15 @@ class CohortEngine:
             padded_cs = list(cstate_list) + \
                 [cstate_list[-1]] * (bucket - len(cstate_list))
             cstacked = jax.tree.map(lambda *xs: jnp.stack(xs), *padded_cs)
-            deltas, new_cs = self._jit_cohort_s(
-                params, stacked, cstacked, self.strategy.shared_state())
+            with span("engine.cohort", bucket=bucket):
+                deltas, new_cs = self._jit_cohort_s(
+                    params, stacked, cstacked, self.strategy.shared_state())
             return self._emit(DeltaBank(stacked=deltas, k=k,
                                         stats=self.stats, cstates=new_cs))
-        stacked, k, _ = self._pad_stack(batch_list)
-        return self._emit(DeltaBank(stacked=self._jit_cohort(params,
-                                                             stacked),
-                                    k=k, stats=self.stats))
+        stacked, k, bucket = self._pad_stack(batch_list)
+        with span("engine.cohort", bucket=bucket):
+            deltas = self._jit_cohort(params, stacked)
+        return self._emit(DeltaBank(stacked=deltas, k=k, stats=self.stats))
 
     def update_cohort_mean(self, params, batch_list: List):
         """Cohort deltas reduced to their mean (sync FedAvg-family rounds).

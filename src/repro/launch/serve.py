@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import json
 import os
 import time
@@ -70,6 +71,7 @@ from repro.core.types import PersAFLConfig
 from repro.data import synthetic_token_batch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
+from repro.spans import span
 
 
 def _personalize_len(cfg, n: int) -> int:
@@ -121,21 +123,38 @@ def _init_batch(cfg, tokens):
     return batch
 
 
+def _generate(step, params, cache, tok, start, stop):
+    """The greedy token loop from position ``start`` to ``stop``: one step
+    dispatch and one argmax per token, since each token is the previous
+    step's argmax.  ``tok`` and every generated token are ``[..., 1]``;
+    returns them joined on the last axis, or None for an empty range."""
+    generated = []
+    for pos in range(start, stop):
+        # the first step traces and loads the call's fresh step program
+        with span("decode.first_step" if pos == start else "decode.step"):
+            logits, cache = step(params, cache, tok, jnp.int32(pos))
+            tok = jnp.argmax(logits[..., -1, :], axis=-1)[..., None] \
+                .astype(jnp.int32)
+            generated.append(tok)
+    with span("decode.collect"):
+        jax.block_until_ready(tok)
+        return jnp.concatenate(generated, axis=-1) if generated else None
+
+
 def _decode_shared(cfg, params, prompt, max_len, prompt_len):
     """Batched decode with the shared global params (no personalization)."""
-    cache = api.init_cache(cfg, params, _init_batch(cfg, prompt[:, :1]),
-                           max_len, cfg.activation_dtype)
-    prefill = jax.jit(make_prefill(cfg))
-    step = jax.jit(lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos))
-    cache = prefill(params, cache, prompt)
-    tok = prompt[:, -1:]
-    generated = []
-    for pos in range(prompt_len - 1, max_len - 1):
-        logits, cache = step(params, cache, tok, jnp.int32(pos))
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        generated.append(tok)
-    jax.block_until_ready(tok)
-    return jnp.concatenate(generated, axis=1) if generated else None
+    with span("decode", rows=prompt.shape[0]):
+        with span("decode.init"):
+            cache = api.init_cache(cfg, params,
+                                   _init_batch(cfg, prompt[:, :1]),
+                                   max_len, cfg.activation_dtype)
+        prefill = jax.jit(make_prefill(cfg))
+        step = jax.jit(
+            lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos))
+        with span("decode.prefill"):
+            cache = prefill(params, cache, prompt)
+        return _generate(step, params, cache, prompt[:, -1:],
+                         prompt_len - 1, max_len - 1)
 
 
 def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
@@ -156,24 +175,22 @@ def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
     else:
         p_axes = 0
     prompt_u = prompt[:, None, :]                      # [U, 1, L]
-    init = jax.vmap(lambda p, t: api.init_cache(
-        cfg, p, _init_batch(cfg, t[:, :1]), max_len, cfg.activation_dtype),
-        in_axes=(p_axes, 0))
-    cache = init(heads, prompt_u)
-    prefill = jax.jit(jax.vmap(make_prefill(cfg), in_axes=(p_axes, 0, 0)))
-    step = jax.jit(jax.vmap(
-        lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos),
-        in_axes=(p_axes, 0, 0, None)))
-    cache = prefill(heads, cache, prompt_u)
-    tok = prompt_u[:, :, -1:]                          # [U, 1, 1]
-    generated = []
-    for pos in range(prompt_len - 1, max_len - 1):
-        logits, cache = step(heads, cache, tok, jnp.int32(pos))
-        tok = jnp.argmax(logits[:, :, -1, :], axis=-1)[..., None] \
-            .astype(jnp.int32)                         # [U, 1, 1]
-        generated.append(tok[:, 0])
-    jax.block_until_ready(tok)
-    return jnp.concatenate(generated, axis=1) if generated else None
+    with span("decode", rows=prompt.shape[0]):
+        init = jax.vmap(lambda p, t: api.init_cache(
+            cfg, p, _init_batch(cfg, t[:, :1]), max_len,
+            cfg.activation_dtype), in_axes=(p_axes, 0))
+        with span("decode.init"):
+            cache = init(heads, prompt_u)
+        prefill = jax.jit(jax.vmap(make_prefill(cfg),
+                                   in_axes=(p_axes, 0, 0)))
+        step = jax.jit(jax.vmap(
+            lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos),
+            in_axes=(p_axes, 0, 0, None)))
+        with span("decode.prefill"):
+            cache = prefill(heads, cache, prompt_u)
+        out = _generate(step, heads, cache, prompt_u[:, :, -1:],  # [U,1,1]
+                        prompt_len - 1, max_len - 1)
+    return None if out is None else out[:, 0]
 
 
 def _serve_transport(args, server) -> None:
@@ -274,6 +291,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "its own --process-id)")
     ap.add_argument("--process-id", type=int, default=0,
                     help="this process's rank in [0, --num-processes)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record the serve and decode run in a "
+                         "jax.profiler trace under DIR: device planes "
+                         "and the program's persafl.* spans")
     return ap
 
 
@@ -328,8 +349,8 @@ def personalize(args, cfg, server):
     return [t.user for t in tickets]
 
 
-def main():
-    args = build_parser().parse_args()
+def main(argv=None):
+    args = build_parser().parse_args(argv)
     enable_compile_cache()
 
     if args.listen is not None:
@@ -352,6 +373,15 @@ def main():
     key = jax.random.PRNGKey(args.seed)
     params = api.init_params(cfg, key)
 
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with profile:
+        serve_and_decode(args, cfg, params, key)
+
+
+def serve_and_decode(args, cfg, params, key):
+    """Personalize and decode (or serve over the socket) as the flags
+    say, and write the run's record under ``--out``."""
     B = args.requests
     max_len = args.prompt_len + args.tokens
     prompt = jax.random.randint(key, (B, args.prompt_len), 0, cfg.vocab)

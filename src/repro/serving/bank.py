@@ -73,6 +73,7 @@ from repro.core.subset import SubsetSpec, merge_subset
 from repro.core.subset import row_nbytes as _row_nbytes
 from repro.core.types import ServerState
 from repro.fl.engine import DeltaBank
+from repro.spans import span
 
 
 class DeltaRing:
@@ -260,48 +261,8 @@ class DeltaRing:
         """
         m = len(self._pending)
         if m:
-            groups: Dict[int, Tuple[DeltaBank, List[Tuple[int, int]]]] = {}
-            for bank, row, tau in self._pending:
-                groups.setdefault(id(bank), (bank, []))[1].append((row, tau))
-            if self.robust is not None:
-                # one call for the whole window — the defense calibrates
-                # over every pending admission, current bank and straggler
-                # banks together (a lone straggler row would otherwise set
-                # its own clip median); row norms are reduced on device
-                # ([capacity] f32 is all that crosses to host)
-                per_bank, info = robust_flush_weights(
-                    groups, beta=beta, count=m, damping=damping,
-                    tau_max=self.tau_max, method=self.robust,
-                    clip_norm=self.clip_norm, trim_frac=self.trim_frac)
-                for key in ("clipped", "trimmed", "nonfinite"):
-                    self.stats[f"robust_{key}"] += info[key]
-            for key, (bank, rows) in groups.items():
-                if self.robust is not None:
-                    weights, keep = per_bank[key]
-                    # non-finite rows masked out of the stack so
-                    # 0-weights cannot leak NaNs (0×NaN=NaN)
-                    stack = bank.stacked if bool(keep.all()) \
-                        else mask_rows(bank.stacked, keep)
-                else:
-                    weights = admission_weights(
-                        bank.capacity, rows, beta=beta, count=m,
-                        damping=damping, tau_max=self.tau_max)
-                    stack = bank.stacked
-                # admission order, deduped (a twice-admitted row already
-                # carries its accumulated weight), then the zero-weight
-                # remainder — a full permutation for the ordered apply
-                seen, order = set(), []
-                for r, _ in rows:
-                    if r not in seen:
-                        seen.add(r)
-                        order.append(r)
-                order.extend(r for r in range(bank.capacity)
-                             if r not in seen)
-                state = apply_admitted_rows(
-                    state, stack, weights, len(rows),
-                    staleness_max=max(t for _, t in rows),
-                    staleness_sum=float(sum(t for _, t in rows)),
-                    order=order)
+            with span("ring.apply", window=self.current, rows=m):
+                state = self._apply(state, beta=beta, damping=damping)
         self._pending = []
         self._user_rows = {}
         self.stats["windows"] += 1
@@ -317,7 +278,9 @@ class DeltaRing:
             prev = self.current - 1
             if prev in self._snapshots \
                     and not isinstance(self._snapshots[prev], QuantTree):
-                self._snapshots[prev] = quantize_tree(self._snapshots[prev])
+                with span("ring.demote", window=prev):
+                    self._snapshots[prev] = quantize_tree(
+                        self._snapshots[prev])
         horizon = self.current - self.windows + 1
         for w in [w for w in self._snapshots if w < horizon]:
             del self._snapshots[w]
@@ -325,6 +288,54 @@ class DeltaRing:
         for user in [u for u, (w, _, _) in self._by_user.items()
                      if w < horizon]:
             del self._by_user[user]
+        return state
+
+    def _apply(self, state: ServerState, *, beta: float,
+               damping: float) -> ServerState:
+        """One fused apply per contributing bank of the pending rows."""
+        m = len(self._pending)
+        groups: Dict[int, Tuple[DeltaBank, List[Tuple[int, int]]]] = {}
+        for bank, row, tau in self._pending:
+            groups.setdefault(id(bank), (bank, []))[1].append((row, tau))
+        if self.robust is not None:
+            # one call for the whole window — the defense calibrates
+            # over every pending admission, current bank and straggler
+            # banks together (a lone straggler row would otherwise set
+            # its own clip median); row norms are reduced on device
+            # ([capacity] f32 is all that crosses to host)
+            per_bank, info = robust_flush_weights(
+                groups, beta=beta, count=m, damping=damping,
+                tau_max=self.tau_max, method=self.robust,
+                clip_norm=self.clip_norm, trim_frac=self.trim_frac)
+            for key in ("clipped", "trimmed", "nonfinite"):
+                self.stats[f"robust_{key}"] += info[key]
+        for key, (bank, rows) in groups.items():
+            if self.robust is not None:
+                weights, keep = per_bank[key]
+                # non-finite rows masked out of the stack so
+                # 0-weights cannot leak NaNs (0×NaN=NaN)
+                stack = bank.stacked if bool(keep.all()) \
+                    else mask_rows(bank.stacked, keep)
+            else:
+                weights = admission_weights(
+                    bank.capacity, rows, beta=beta, count=m,
+                    damping=damping, tau_max=self.tau_max)
+                stack = bank.stacked
+            # admission order, deduped (a twice-admitted row already
+            # carries its accumulated weight), then the zero-weight
+            # remainder — a full permutation for the ordered apply
+            seen, order = set(), []
+            for r, _ in rows:
+                if r not in seen:
+                    seen.add(r)
+                    order.append(r)
+            order.extend(r for r in range(bank.capacity)
+                         if r not in seen)
+            state = apply_admitted_rows(
+                state, stack, weights, len(rows),
+                staleness_max=max(t for _, t in rows),
+                staleness_sum=float(sum(t for _, t in rows)),
+                order=order)
         return state
 
     # -- restart warm-start ------------------------------------------------

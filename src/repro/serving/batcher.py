@@ -43,6 +43,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 from repro.core.types import PersAFLConfig
 from repro.fl.api import strategy as _strategy
 from repro.fl.engine import CohortEngine, DeltaBank
+from repro.spans import span
 
 MODES = ("B", "C")
 
@@ -115,7 +116,7 @@ class MicroBatcher:
         self._cap_window: int = -1
         self._user_rows: Dict[object, int] = {}
         self.stats = {"submitted": 0, "drains": 0, "cohort_calls": 0,
-                      "max_coalesced": 0, "shard_padding": 0, "dropped": 0,
+                      "max_coalesced": 0, "dropped": 0,
                       "fairness_capped": 0}
 
     def __len__(self) -> int:
@@ -133,7 +134,8 @@ class MicroBatcher:
         return zlib.crc32(str(user).encode()) % self.n_shards
 
     def _layout(self, reqs: List[Tuple[Ticket, Dict]]):
-        """Slice-major cohort layout -> (batch_list, [(ticket, row)]).
+        """Slice-major cohort layout -> (batch_list, [(ticket, row)],
+        filler rows).
 
         With one cohort slice the engine's own tail padding suffices; with
         N the per-slice slot count pads to a pow2 so the total is exactly
@@ -142,11 +144,13 @@ class MicroBatcher:
         of which slice each request landed on: admission order must be a
         mesh-independent total order on the window's rows (the ring feeds
         it to the ordered window apply), and slice-major order would
-        permute with the mesh shape.
+        permute with the mesh shape.  The filler rows are the engine's
+        padding as much as its own bucket tail is, and are counted with it
+        (``padding_waste``).
         """
         if self.n_shards == 1:
             return ([b for _, b in reqs],
-                    [(t, i) for i, (t, _) in enumerate(reqs)])
+                    [(t, i) for i, (t, _) in enumerate(reqs)], 0)
         shards: List[List[Tuple[int, Dict]]] = \
             [[] for _ in range(self.n_shards)]
         for qi, (t, _) in enumerate(reqs):
@@ -162,9 +166,9 @@ class MicroBatcher:
                     row_of[qi] = si * per + j
                 else:
                     batch_list.append(fill)
-                    self.stats["shard_padding"] += 1
-        return batch_list, [(t, row_of[qi])
-                            for qi, (t, _) in enumerate(reqs)]
+        return (batch_list,
+                [(t, row_of[qi]) for qi, (t, _) in enumerate(reqs)],
+                len(batch_list) - len(reqs))
 
     def drain(self, current: int, snapshot_fn: Callable[[int], object], *,
               tau_max: int) -> Iterator[Tuple[str, int, DeltaBank,
@@ -182,6 +186,25 @@ class MicroBatcher:
         queue, self._queue = self._queue, []
         if not queue:
             return
+        groups = None
+        while groups is None or groups:
+            # one span per group; the first also covers the grouping
+            with span("batcher.drain", window=current):
+                if groups is None:
+                    groups = self._group(queue, current, tau_max)
+                    if not groups:
+                        return
+                (mode, stamp), reqs = groups.pop(0)
+                batch_list, placed, filler = self._layout(reqs)
+                self.stats["cohort_calls"] += 1
+                engine = self.engines[mode]
+                engine.stats["padding_waste"] += filler
+                bank = engine.update_cohort(snapshot_fn(stamp), batch_list)
+            yield mode, stamp, bank, placed
+
+    def _group(self, queue, current: int, tau_max: int):
+        """Admission-filtered requests grouped by ``(mode, stamp)``,
+        oldest stamp first."""
         if current != self._cap_window:        # window rolled: caps reset
             self._cap_window = current
             self._user_rows = {}
@@ -204,10 +227,4 @@ class MicroBatcher:
                 self._user_rows[ticket.user] = used + 1
             groups.setdefault((ticket.mode, ticket.stamp), []).append(
                 (ticket, batch))
-        for (mode, stamp), reqs in sorted(groups.items(),
-                                          key=lambda kv: kv[0][1]):
-            batch_list, placed = self._layout(reqs)
-            self.stats["cohort_calls"] += 1
-            bank = self.engines[mode].update_cohort(snapshot_fn(stamp),
-                                                    batch_list)
-            yield mode, stamp, bank, placed
+        return sorted(groups.items(), key=lambda kv: kv[0][1])

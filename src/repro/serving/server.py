@@ -75,10 +75,20 @@ from repro.fl.engine import CohortEngine, DeltaBank
 from repro.serving.bank import DeltaRing
 from repro.serving.batcher import (MODES, MicroBatcher, Ticket,
                                    personalize_strategy)
+from repro.spans import span
 
 
 def _own_copy(params):
     return jax.tree.map(lambda x: jnp.array(x), params)
+
+
+@jax.jit
+def heads_fp32(snapshot, stacked):
+    """The fp32 head bank ``snapshot − delta`` of a cohort: f32 compute,
+    param dtype out; one compile per stacked shape."""
+    return jax.tree.map(
+        lambda p, d: (p[None].astype(jnp.float32) - d).astype(p.dtype),
+        snapshot, stacked)
 
 
 def _row_of(handle, row: int):
@@ -208,10 +218,6 @@ class PersonalizationServer:
         # re-quantizing (error feedback); LRU-evicted like the head cache
         self._residuals: "collections.OrderedDict" = \
             collections.OrderedDict()
-        # one compile per (stacked-shape); reused every flush
-        self._jit_heads = jax.jit(lambda p, s: jax.tree.map(
-            lambda pp, ss: (pp[None].astype(jnp.float32) - ss).astype(
-                pp.dtype), p, s))
 
     # -- request path ------------------------------------------------------
 
@@ -240,6 +246,10 @@ class PersonalizationServer:
         admission + head-cache insertion (all device handles, no
         transfers).
         """
+        with span("flush", window=self.ring.current):
+            return self._flush()
+
+    def _flush(self) -> int:
         served = 0
         for mode, stamp, bank, placed in self.batcher.drain(
                 self.ring.current, self.ring.snapshot,
@@ -260,35 +270,40 @@ class PersonalizationServer:
                 # subset tree (same pruned structure) — heads are subset
                 # pytrees
                 heads = DeltaBank(
-                    stacked=self._jit_heads(
+                    stacked=heads_fp32(
                         self.ring.subset_snapshot(stamp), bank.stacked),
                     k=bank.k, stats=self._engine_stats)
                 self.ring.retain(heads)  # head rows live with the bank
-            for ticket, row in placed:
-                # the ring is the admission authority: the batcher's drain
-                # bound normally pre-filters, but a refusal here must not
-                # serve a head whose delta never reached the global apply.
-                # The refusal CAUSE must survive to poll: a fairness-cap
-                # refusal is "capped" (re-submit next window), never
-                # "dropped" (which poll reports as a tau_max violation)
-                verdict = self.ring.admit_row(ticket.user, bank, row,
-                                              ticket.tau)
-                if verdict != "admitted":
-                    ticket.status = verdict
-                    continue
-                self._cache_head(ticket.user, heads, row)
-                if resbank is not None:
-                    # the NEW residual (this row's quantization error)
-                    # replaces the user's banked one — consumed-and-
-                    # replaced is exactly the EF recurrence.  Refused rows
-                    # never apply, so their user keeps the old residual.
-                    self._cache_residual(ticket.user, resbank, row)
-                # the ticket owns its result: poll resolves THIS handle,
-                # not whatever head the user's latest flush produced
-                ticket.head = (heads, row)
-                ticket.window = self.ring.current
-                ticket.status = "done"
-                served += 1
+            with span("ring.admit", window=self.ring.current,
+                      rows=len(placed)):
+                for ticket, row in placed:
+                    # the ring is the admission authority: the batcher's
+                    # drain bound normally pre-filters, but a refusal here
+                    # must not serve a head whose delta never reached the
+                    # global apply.  The refusal CAUSE must survive to
+                    # poll: a fairness-cap refusal is "capped" (re-submit
+                    # next window), never "dropped" (which poll reports as
+                    # a tau_max violation)
+                    verdict = self.ring.admit_row(ticket.user, bank, row,
+                                                  ticket.tau)
+                    if verdict != "admitted":
+                        ticket.status = verdict
+                        continue
+                    self._cache_head(ticket.user, heads, row)
+                    if resbank is not None:
+                        # the NEW residual (this row's quantization error)
+                        # replaces the user's banked one — consumed-and-
+                        # replaced is exactly the EF recurrence.  Refused
+                        # rows never apply, so their user keeps the old
+                        # residual.
+                        self._cache_residual(ticket.user, resbank, row)
+                    # the ticket owns its result: poll resolves THIS
+                    # handle, not whatever head the user's latest flush
+                    # produced
+                    ticket.head = (heads, row)
+                    ticket.window = self.ring.current
+                    ticket.status = "done"
+                    served += 1
         return served
 
     # -- quantized banking (error feedback) --------------------------------
@@ -302,8 +317,10 @@ class PersonalizationServer:
         rows replace the users' entries after admission.  Returns
         ``(delta QuantizedBank, residual QuantizedBank)``.
         """
-        residual = self._residual_stack(bank.stacked, placed)
-        qstack, resstack = ef_quantize_stack(bank.stacked, residual)
+        with span("ef.residual", rows=len(placed)):
+            residual = self._residual_stack(bank.stacked, placed)
+        with span("ef.quantize", rows=len(placed)):
+            qstack, resstack = ef_quantize_stack(bank.stacked, residual)
         qbank = QuantizedBank(qstack, k=bank.k, stats=self._engine_stats)
         resbank = QuantizedBank(resstack, k=bank.k,
                                 stats=self._engine_stats)
@@ -356,6 +373,10 @@ class PersonalizationServer:
         window rotated out: its bank is gone) — all mean the user must
         re-submit against a fresh snapshot.
         """
+        with span("poll", window=ticket.window):
+            return self._poll(ticket)
+
+    def _poll(self, ticket: Ticket):
         if ticket.status == "queued":
             return None
         if ticket.status == "dropped":
@@ -424,10 +445,12 @@ class PersonalizationServer:
         next flush computes them against their *stamped* (retained)
         snapshot and admits them into the new window's weight vector.
         """
-        if flush:
-            self.flush()
-        self.state = self.ring.advance(self.state, beta=self.pcfg.beta,
-                                       damping=self.pcfg.staleness_damping)
+        with span("advance", window=self.ring.current):
+            if flush:
+                self.flush()
+            self.state = self.ring.advance(
+                self.state, beta=self.pcfg.beta,
+                damping=self.pcfg.staleness_damping)
 
     # -- restart warm-start ------------------------------------------------
 

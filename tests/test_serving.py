@@ -196,7 +196,9 @@ def test_shard_major_layout_preserves_row_identity():
     srv.batcher.n_shards = 4             # force the shard-major path
     tickets = [srv.submit(f"u{i}", user_batch(i)) for i in range(5)]
     srv.flush()
-    assert srv.stats["batcher_shard_padding"] > 0
+    # every row of the cohort's stack that holds no request is filler
+    rows = jax.tree.leaves(tickets[0].head[0].stacked)[0].shape[0]
+    assert srv.stats["padding_waste"] == rows - 5 > 0
     for i, t in enumerate(tickets):
         _close(srv.poll(t), personalize_me(loss, params, user_batch(i),
                                            pcfg.lam, pcfg.inner_eta,
