@@ -59,6 +59,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import contextlib
+import functools
 import json
 import os
 import time
@@ -130,7 +131,8 @@ def _generate(step, params, cache, tok, start, stop):
     returns them joined on the last axis, or None for an empty range."""
     generated = []
     for pos in range(start, stop):
-        # the first step traces and loads the call's fresh step program
+        # the first step waits for the prefill on the device, and on the
+        # first call of a shape it also traces and compiles the step
         with span("decode.first_step" if pos == start else "decode.step"):
             logits, cache = step(params, cache, tok, jnp.int32(pos))
             tok = jnp.argmax(logits[..., -1, :], axis=-1)[..., None] \
@@ -141,16 +143,51 @@ def _generate(step, params, cache, tok, start, stop):
         return jnp.concatenate(generated, axis=-1) if generated else None
 
 
+@functools.lru_cache(maxsize=8)
+def _decode_programs(cfg, max_len, personal):
+    """The decode call's three device programs: cache init, prefill and
+    token step, built once per key and kept.  JAX's jit cache is keyed on
+    the function object, so fresh ``jax.jit`` objects in every call would
+    retrace and reload both programs each time; kept ones run the
+    executables of the call before, and compile again only for a new
+    shape (user count, prompt length).
+
+    ``personal`` says which leaves carry the user axis: None for the
+    shared params (nothing is vmapped), True for every leaf, or a
+    personal-subset mask flattened to ``(treedef, bools)``.  The programs
+    take params, cache, prompt and position as arguments and close over
+    no array.  The step stays an anonymous lambda (``jit__lambda``, the
+    program ``decode_step_ms`` reads); init and prefill compile as
+    ``jit_init_decode_cache`` and ``jit_prefill``.
+    """
+    with span("decode.build", programs=3):
+        if personal is None or personal is True:
+            p_axes = 0
+        else:
+            treedef, mask = personal
+            p_axes = treedef.unflatten([0 if m else None for m in mask])
+
+        def vmap(fn, *axes):
+            if personal is None:
+                return fn
+            return jax.vmap(fn, in_axes=(p_axes, *axes))
+
+        def init_decode_cache(p, t):
+            return api.init_cache(cfg, p, _init_batch(cfg, t[:, :1]),
+                                  max_len, cfg.activation_dtype)
+
+        return (jax.jit(vmap(init_decode_cache, 0)),
+                jax.jit(vmap(make_prefill(cfg), 0, 0)),
+                jax.jit(vmap(lambda p, c, t, pos: api.decode_step(
+                    cfg, p, c, t, pos), 0, 0, None)))
+
+
 def _decode_shared(cfg, params, prompt, max_len, prompt_len):
     """Batched decode with the shared global params (no personalization)."""
     with span("decode", rows=prompt.shape[0]):
+        init, prefill, step = _decode_programs(cfg, max_len, None)
         with span("decode.init"):
-            cache = api.init_cache(cfg, params,
-                                   _init_batch(cfg, prompt[:, :1]),
-                                   max_len, cfg.activation_dtype)
-        prefill = jax.jit(make_prefill(cfg))
-        step = jax.jit(
-            lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos))
+            cache = init(params, prompt)
         with span("decode.prefill"):
             cache = prefill(params, cache, prompt)
         return _generate(step, params, cache, prompt[:, -1:],
@@ -171,21 +208,15 @@ def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
     if spec is not None:
         from repro.core.subset import merge_subset
         heads = merge_subset(params, heads)
-        p_axes = jax.tree.map(lambda m: 0 if m else None, spec.mask(params))
+        mask, treedef = jax.tree.flatten(spec.mask(params))
+        personal = (treedef, tuple(mask))
     else:
-        p_axes = 0
+        personal = True
     prompt_u = prompt[:, None, :]                      # [U, 1, L]
     with span("decode", rows=prompt.shape[0]):
-        init = jax.vmap(lambda p, t: api.init_cache(
-            cfg, p, _init_batch(cfg, t[:, :1]), max_len,
-            cfg.activation_dtype), in_axes=(p_axes, 0))
+        init, prefill, step = _decode_programs(cfg, max_len, personal)
         with span("decode.init"):
             cache = init(heads, prompt_u)
-        prefill = jax.jit(jax.vmap(make_prefill(cfg),
-                                   in_axes=(p_axes, 0, 0)))
-        step = jax.jit(jax.vmap(
-            lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos),
-            in_axes=(p_axes, 0, 0, None)))
         with span("decode.prefill"):
             cache = prefill(heads, cache, prompt_u)
         out = _generate(step, heads, cache, prompt_u[:, :, -1:],  # [U,1,1]
