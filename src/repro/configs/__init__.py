@@ -10,6 +10,7 @@ from repro.configs.base import (ArchConfig, InputShape, INPUT_SHAPES,
                                 get_shape, reduce_for_smoke)
 
 from repro.configs.zamba2_1p2b import CONFIG as _zamba2
+from repro.configs.zamba2_7b import CONFIG as _zamba2_7b
 from repro.configs.codeqwen1p5_7b import CONFIG as _codeqwen
 from repro.configs.gemma2_2b import CONFIG as _gemma2
 from repro.configs.deepseek_v3_671b import CONFIG as _deepseek
@@ -23,7 +24,8 @@ from repro.configs.mamba2_130m import CONFIG as _mamba2
 _REGISTRY: Dict[str, ArchConfig] = {
     c.arch_id: c
     for c in (_zamba2, _codeqwen, _gemma2, _deepseek, _minitron,
-              _internvl, _whisper, _granite, _qwen110, _mamba2)
+              _internvl, _whisper, _granite, _qwen110, _mamba2,
+              _zamba2_7b)
 }
 
 

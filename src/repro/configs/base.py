@@ -107,7 +107,14 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
-    attn_every: int = 0          # hybrid (zamba2): shared attn block period
+    # hybrid (zamba2, arXiv:2411.15242): the layers whose Mamba2 mixer is
+    # fed by a shared transformer block; the i-th such invocation runs
+    # block i % num_mem_blocks with its own linear and, with adapter_rank,
+    # its own LoRA adapter on the shared MLP's gate-up projection
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
+    attn_scale: float = 0.0      # attention score scale; 0 -> head_dim**-0.5
     use_mtp: bool = False        # deepseek multi-token prediction head
 
     # encoder-decoder (whisper)
@@ -124,9 +131,9 @@ class ArchConfig:
     remat: bool = True
     remat_policy: str = "full"   # full | dots (save matmul outputs)
     # train-time microbatching (gradient accumulation); per-shape override
-    # chosen so activations fit v5e HBM — see DESIGN.md §5.
+    # chosen so activations fit v5e HBM.
     train_microbatches: int = 1
-    # which shapes this arch supports (skips recorded in DESIGN.md)
+    # which shapes this arch supports
     skip_shapes: Tuple[str, ...] = ()
 
     # PersA-FL defaults for this arch (see repro.core)
@@ -166,8 +173,8 @@ class ArchConfig:
                 per_layer += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                 per_layer += m.kv_lora_rank * n_q * (m.qk_nope_head_dim + m.v_head_dim)
                 per_layer += n_q * m.v_head_dim * d
-            elif self.attn_every:
-                pass  # hybrid: shared attn counted once below
+            elif self.hybrid_layer_ids:
+                pass  # hybrid: shared blocks counted once below
             else:
                 per_layer += d * hd * (n_q + 2 * n_kv) + n_q * hd * d
         if self.moe is not None:
@@ -179,10 +186,15 @@ class ArchConfig:
             total = emb + L * per_layer + moe_layers * per_layer_moe + dense
         elif self.family == "ssm":
             total = emb + L * per_layer
-        elif self.attn_every:
-            # zamba2: shared attn+mlp block, params counted once
-            shared = 2 * d * hd * (n_q + 2 * n_kv) + n_q * hd * d + 3 * d * self.d_ff
-            total = emb + L * per_layer + shared
+        elif self.hybrid_layer_ids:
+            # zamba2: the shared attn+mlp blocks counted once each, then
+            # each invocation's linear and adapter
+            block = 2 * d * hd * (n_q + 2 * n_kv) + n_q * hd * d \
+                + 3 * d * self.d_ff
+            n_inv = sum(i < L for i in self.hybrid_layer_ids)
+            inv = d * d + self.adapter_rank * (d + 2 * self.d_ff)
+            total = emb + L * per_layer + self.num_mem_blocks * block \
+                + n_inv * inv
         else:
             per_layer += 3 * d * self.d_ff  # gate/up/down
             total = emb + L * per_layer
@@ -247,8 +259,13 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
                                 v_head_dim=hd)
     if cfg.ssm is not None:
         repl["ssm"] = dataclasses.replace(cfg.ssm, state_dim=16, head_dim=32, chunk=16)
-    if cfg.attn_every:
-        repl["attn_every"] = 2
+    if cfg.hybrid_layer_ids:
+        # both layers invoke a shared block where the model has two
+        repl["hybrid_layer_ids"] = (0, 1)[-cfg.num_mem_blocks:]
+        repl["adapter_rank"] = min(cfg.adapter_rank, 8)
+        if cfg.attn_scale:   # the same scale relative to the head size
+            repl["attn_scale"] = cfg.attn_scale \
+                * (cfg.resolved_head_dim / hd) ** 0.5
     if cfg.is_encdec:
         repl["enc_layers"] = 2
         repl["enc_len"] = 16

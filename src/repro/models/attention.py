@@ -27,7 +27,7 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def init_attn(key, cfg: ArchConfig, d_in: Optional[int] = None):
-    """d_in lets hybrid blocks feed concat(h, emb) (zamba2)."""
+    """d_in lets hybrid blocks feed concat(h, emb) (zamba2: 2 * d_model)."""
     d = cfg.d_model
     d_in = d_in or d
     hd = cfg.resolved_head_dim
@@ -64,17 +64,18 @@ def _project_qkv(cfg: ArchConfig, p, x, kv_x=None):
     return q, k, v
 
 
-def sdpa(q, k, v, *, mask=None, cap: float = 0.0):
+def sdpa(q, k, v, *, mask=None, cap: float = 0.0, scale: float = 0.0):
     """Grouped scaled-dot-product attention.
 
     q: (B,S,Hq,hd); k,v: (B,T,Hkv,hd); Hq % Hkv == 0.
     mask: broadcastable to (B,1,1,S,T), True = attend.
+    scale: the score scale; 0 -> hd ** -0.5 (a config's ``attn_scale``).
     """
     B, S, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     group = Hq // Hkv
     qg = q.reshape(B, S, Hkv, group, hd)
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
     logits = jnp.einsum("bskgh,btkh->bkgst", qg, k).astype(jnp.float32) * scale
     logits = softcap(logits, cap)
     if mask is not None:
@@ -115,7 +116,8 @@ def attn_forward(cfg: ArchConfig, p, x, *, positions, window: int = 0,
     mask = None
     if causal:
         mask = causal_mask(q.shape[1], k.shape[1], 0, window, local_flag)
-    out = sdpa(q, k, v, mask=mask, cap=cfg.attn_softcap)
+    out = sdpa(q, k, v, mask=mask, cap=cfg.attn_softcap,
+               scale=cfg.attn_scale)
     out = shard_activation(out, "attn_out")
     B, S = x.shape[:2]
     return out.reshape(B, S, -1) @ p["wo"].astype(x.dtype), (k, v)
@@ -152,7 +154,7 @@ def attn_decode(cfg: ArchConfig, p, x, k_cache, v_cache, pos, *,
         m = m & win
     mask = m[None, None, None, None, :]
     out = sdpa(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype),
-               mask=mask, cap=cfg.attn_softcap)
+               mask=mask, cap=cfg.attn_softcap, scale=cfg.attn_scale)
     B = x.shape[0]
     return out.reshape(B, 1, -1) @ p["wo"].astype(x.dtype), k_cache, v_cache
 

@@ -63,6 +63,19 @@ def _causal_conv(xbc, conv_w, conv_b):
     return jax.nn.silu(out + conv_b.astype(xbc.dtype))
 
 
+def gated_norm(cfg: ArchConfig, y, z, gamma):
+    """RMSNorm of the gated output ``y * silu(z)``, over each of the
+    ``n_groups`` groups of ``d_inner / n_groups`` channels (mamba_ssm's
+    ``RMSNormGated`` with ``group_size``)."""
+    x = y * jax.nn.silu(z)
+    g = cfg.ssm.n_groups
+    if g == 1:   # the grouped form rounds differently in bfloat16
+        return rms_norm(x, gamma, cfg.norm_eps)
+    grouped = x.reshape(x.shape[:-1] + (g, x.shape[-1] // g))
+    return rms_norm(grouped, gamma.reshape(g, -1),
+                    cfg.norm_eps).reshape(x.shape)
+
+
 def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int):
     """Chunked SSD scan (pure jnp reference; f32 internals).
 
@@ -142,7 +155,7 @@ def mamba2_forward(cfg: ArchConfig, p, x, *, use_kernel: bool = False):
         * xs.astype(jnp.float32)
     y = y.reshape(Bb, S, d_inner).astype(dt_)
     y = shard_activation(y, "ssm_out")
-    y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+    y = gated_norm(cfg, y, z, p["gate_norm"])
     return y @ p["out_proj"].astype(dt_)
 
 
@@ -190,6 +203,6 @@ def mamba2_decode(cfg: ArchConfig, p, x, cache) -> Tuple[jnp.ndarray, dict]:
     y = jnp.einsum("bhpn,bhn->bhp", state, Cm)
     y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xs
     y = y.reshape(Bb, d_inner).astype(dt_)
-    y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+    y = gated_norm(cfg, y, z, p["gate_norm"])
     out = (y @ p["out_proj"].astype(dt_))[:, None, :]
     return out, {"conv": new_conv, "state": state}

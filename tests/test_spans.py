@@ -152,9 +152,10 @@ def test_program_compiles_under_its_own_name(name):
     assert f"module @jit_{name} " in text
 
 
-def test_only_the_span_helper_opens_trace_annotations():
+@pytest.mark.parametrize("opener", ["TraceAnnotation", "named_scope"])
+def test_only_the_span_helper_opens_trace_annotations(opener):
     users = [p.relative_to(SRC).as_posix() for p in SRC.rglob("*.py")
-             if "TraceAnnotation" in p.read_text()]
+             if opener in p.read_text()]
     assert users == ["repro/spans.py"]
 
 
