@@ -145,22 +145,31 @@ def _generate(step, params, cache, tok, start, stop):
 
 @functools.lru_cache(maxsize=8)
 def _decode_programs(cfg, max_len, personal):
-    """The decode call's three device programs: cache init, prefill and
-    token step, built once per key and kept.  JAX's jit cache is keyed on
-    the function object, so fresh ``jax.jit`` objects in every call would
-    retrace and reload both programs each time; kept ones run the
+    """The decode call's four device programs: the weights' cast to the
+    compute dtype (``api.decode_weights``), cache init, prefill and token
+    step, built once per key and kept.  JAX's jit cache is keyed on the
+    function object, so fresh ``jax.jit`` objects in every call would
+    retrace and reload the programs each time; kept ones run the
     executables of the call before, and compile again only for a new
-    shape (user count, prompt length).
+    shape (user count, prompt length).  The cast runs once a call, so
+    the token step reads weights already in the compute dtype instead of
+    casting them again at every token.  Cache init and the prefill take
+    the weights as given: the prefill's scan already hoists its casts
+    out of its loop, and on TPU v5e its loop rounds differently on a
+    pre-cast ``in_proj`` than on the float32 one, where the step is bit
+    for bit the same on either.  The call casts after the prefill, so
+    the cast and the prefill's own hoisted casts are never held at once.
 
     ``personal`` says which leaves carry the user axis: None for the
     shared params (nothing is vmapped), True for every leaf, or a
     personal-subset mask flattened to ``(treedef, bools)``.  The programs
     take params, cache, prompt and position as arguments and close over
     no array.  The step stays an anonymous lambda (``jit__lambda``, the
-    program ``decode_step_ms`` reads); init and prefill compile as
-    ``jit_init_decode_cache`` and ``jit_prefill``.
+    program ``decode_step_ms`` reads); the others compile as
+    ``jit_decode_weights``, ``jit_init_decode_cache`` and ``jit_prefill``.
+    The cast is elementwise, so it needs no vmap over the user axis.
     """
-    with span("decode.build", programs=3):
+    with span("decode.build", programs=4):
         if personal is None or personal is True:
             p_axes = 0
         else:
@@ -172,11 +181,15 @@ def _decode_programs(cfg, max_len, personal):
                 return fn
             return jax.vmap(fn, in_axes=(p_axes, *axes))
 
+        def decode_weights(p):
+            return api.decode_weights(cfg, p)
+
         def init_decode_cache(p, t):
             return api.init_cache(cfg, p, _init_batch(cfg, t[:, :1]),
                                   max_len, cfg.activation_dtype)
 
-        return (jax.jit(vmap(init_decode_cache, 0)),
+        return (jax.jit(decode_weights),
+                jax.jit(vmap(init_decode_cache, 0)),
                 jax.jit(vmap(make_prefill(cfg), 0, 0)),
                 jax.jit(vmap(lambda p, c, t, pos: api.decode_step(
                     cfg, p, c, t, pos), 0, 0, None)))
@@ -185,12 +198,14 @@ def _decode_programs(cfg, max_len, personal):
 def _decode_shared(cfg, params, prompt, max_len, prompt_len):
     """Batched decode with the shared global params (no personalization)."""
     with span("decode", rows=prompt.shape[0]):
-        init, prefill, step = _decode_programs(cfg, max_len, None)
+        cast, init, prefill, step = _decode_programs(cfg, max_len, None)
         with span("decode.init"):
             cache = init(params, prompt)
         with span("decode.prefill"):
             cache = prefill(params, cache, prompt)
-        return _generate(step, params, cache, prompt[:, -1:],
+        with span("decode.cast"):
+            view = cast(params)
+        return _generate(step, view, cache, prompt[:, -1:],
                          prompt_len - 1, max_len - 1)
 
 
@@ -203,7 +218,9 @@ def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
     stacked *subset* tree; merging it over the shared backbone yields a
     mixed tree whose personal leaves carry the user axis and whose backbone
     leaves do not, and a pytree ``in_axes`` (0 on personal leaves, None on
-    backbone) vmaps it without replicating the backbone per user.
+    backbone) vmaps it without replicating the backbone per user.  The
+    token step runs on the merged tree cast to the compute dtype once per
+    call, backbone leaves included; the cast is dropped with the call.
     """
     if spec is not None:
         from repro.core.subset import merge_subset
@@ -214,12 +231,14 @@ def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
         personal = True
     prompt_u = prompt[:, None, :]                      # [U, 1, L]
     with span("decode", rows=prompt.shape[0]):
-        init, prefill, step = _decode_programs(cfg, max_len, personal)
+        cast, init, prefill, step = _decode_programs(cfg, max_len, personal)
         with span("decode.init"):
             cache = init(heads, prompt_u)
         with span("decode.prefill"):
             cache = prefill(heads, cache, prompt_u)
-        out = _generate(step, heads, cache, prompt_u[:, :, -1:],  # [U,1,1]
+        with span("decode.cast"):
+            view = cast(heads)
+        out = _generate(step, view, cache, prompt_u[:, :, -1:],   # [U,1,1]
                         prompt_len - 1, max_len - 1)
     return None if out is None else out[:, 0]
 

@@ -5,6 +5,7 @@ layer, tests and benchmarks.
     loss_fn(cfg, params, batch)           -> scalar loss  (the f_i of Eq. 2)
     init_cache(cfg, params?, batch, ...)  -> decode cache
     decode_step(cfg, params, cache, tokens, pos) -> (logits, cache)
+    decode_weights(cfg, params)           -> params as decode computes with them
     make_train_batch_spec / make_decode_spec come from repro.launch.specs
 """
 from __future__ import annotations
@@ -68,3 +69,13 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos):
     if cfg.is_encdec:
         return ed.encdec_decode_step(cfg, params, cache, tokens, pos)
     return lm.lm_decode_step(cfg, params, cache, tokens, pos)
+
+
+def decode_weights(cfg: ArchConfig, params):
+    """The weights in the dtype the decode step computes with, cast once
+    so a call's steps do not re-cast them at every token (``ssm_lm``
+    family); same treedef, same served bits.  The other families' decode
+    takes ``params`` as they are."""
+    if cfg.family in ("ssm", "hybrid"):
+        return ssm_lm.decode_weights(cfg, params)
+    return params

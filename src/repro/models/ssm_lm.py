@@ -197,6 +197,29 @@ def init_ssm_cache(cfg: ArchConfig, batch: int, max_len: int, dtype):
     return cache
 
 
+# the leaves the decode step reads only through ``.astype(activation_dtype)``
+# (the embedding ``tok``, every matrix and the conv bias); ``unembed``, the
+# SSM's ``a_log``, ``dt_bias``, ``d_skip`` and the norm gammas are read in
+# float32
+_COMPUTE_LEAVES = frozenset({
+    "tok", "in_proj", "out_proj", "conv_w", "conv_b",
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv", "gate_up", "down",
+    "linear", "adapter_in", "adapter_out"})
+
+
+def decode_weights(cfg: ArchConfig, params):
+    """``params`` as the decode step computes with them: each leaf it
+    reads only through ``.astype(cfg.activation_dtype)`` cast once to that
+    dtype, every other leaf untouched; same treedef.  The step's casts
+    are then no-ops, so it serves the same bits without re-casting the
+    weights at every token."""
+    dt = cfg.activation_dtype
+
+    def view(path, w):
+        return w.astype(dt) if path[-1].key in _COMPUTE_LEAVES else w
+    return jax.tree_util.tree_map_with_path(view, params)
+
+
 def ssm_lm_decode_step(cfg: ArchConfig, params, cache, tokens, pos):
     """tokens (B,1), pos scalar -> (logits (B,1,V), new cache).
 

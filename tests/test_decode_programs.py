@@ -1,6 +1,6 @@
 """The decode call's device programs are built once per key and kept:
-``launch/serve.py``'s ``_decode_programs`` returns the same jitted init,
-prefill and step for every call with the same config, ``max_len`` and
+``launch/serve.py``'s ``_decode_programs`` returns the same jitted cast,
+init, prefill and step for every call with the same config, ``max_len`` and
 user-axis layout, so a call whose arrays alone change runs the programs
 of the call before.  These tests check that the builder engages once per
 key (the ``decode.build`` span), that kept programs serve the same tokens
@@ -136,16 +136,18 @@ def test_alternating_keys_and_shapes_keep_their_own_programs():
 
 @pytest.mark.parametrize("personal", [None, True])
 def test_programs_compile_under_their_own_names(personal):
-    init, prefill, step = serve._decode_programs(CFG, 7, personal)
+    cast, init, prefill, step = serve._decode_programs(CFG, 7, personal)
     params = _heads(2) if personal else PARAMS
     prompt = _prompt(2, 4)[:, None, :] if personal else _prompt(2, 4)
     cache = init(params, prompt)
-    lowered = {"init": init.lower(params, prompt),
+    lowered = {"cast": cast.lower(params),
+               "init": init.lower(params, prompt),
                "prefill": prefill.lower(params, cache, prompt),
                "step": step.lower(params, cache, prompt[..., -1:],
                                   jnp.int32(3))}
     modules = {k: low.as_text().split("module @", 1)[1].split(" ", 1)[0]
                for k, low in lowered.items()}
-    assert modules == {"init": "jit_init_decode_cache",
+    assert modules == {"cast": "jit_decode_weights",
+                       "init": "jit_init_decode_cache",
                        "prefill": "jit_prefill",
                        "step": "jit__lambda"}

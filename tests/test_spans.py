@@ -113,6 +113,7 @@ def test_decode_call_records_each_step(tmp_path):
     decode()
     got = _nesting(_record(tmp_path, decode))
     assert got == {(None, "decode"): 1,
+                   ("decode", "decode.cast"): 1,
                    ("decode", "decode.init"): 1,
                    ("decode", "decode.prefill"): 1,
                    ("decode", "decode.first_step"): 1,
