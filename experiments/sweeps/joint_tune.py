@@ -27,10 +27,8 @@ enforced):
 Artifacts: ``experiments/sweeps/joint_tune.json`` + ``joint_tune.md``
 (fig2-style table), ``experiments/sweeps/joint_tune_journal.jsonl`` (the
 resumable trial journal — re-running skips completed arms),
-``examples/tuned/fig2_winners.json`` (the promoted winning configs, which
-``examples/run_tuned.py`` replays), and one JSONL bench row appended to
-``experiments/bench/BENCH_tune.json`` (arms run / stopped early /
-simulated + wall cost vs the full grid).
+and ``examples/tuned/fig2_winners.json`` (the promoted winning configs,
+which ``examples/run_tuned.py`` replays).
 
     PYTHONPATH=src python experiments/sweeps/joint_tune.py
 
@@ -57,7 +55,6 @@ from repro.tune import (AnyOf, Arm, LossSpike, SweepSpec, TuneRunner,
 FAST = bool(int(os.environ.get("SWEEP_FAST", "0")))
 OUT = os.path.join("experiments", "sweeps")
 JOURNAL = os.path.join(OUT, "joint_tune_journal.jsonl")
-BENCH = os.path.join("experiments", "bench", "BENCH_tune.json")
 WINNERS = os.path.join("examples", "tuned", "fig2_winners.json")
 
 DATASETS = ("mnist", "cifar")
@@ -200,23 +197,6 @@ def main():
                     if g.endswith("/selfstop")}},
         WINNERS, extra={"source": "experiments/sweeps/joint_tune.py",
                         "fast": FAST, "rounds": ROUNDS})
-    os.makedirs(os.path.dirname(BENCH), exist_ok=True)
-    with open(BENCH, "a") as f:
-        f.write(json.dumps({
-            "bench": "tune", "fast": FAST,
-            "arms_total": sum(d["n_arms"] for d in per_ds.values()),
-            "arms_stopped": sum(d["n_stopped"] for d in per_ds.values()),
-            "cost_fraction": {d: per_ds[d]["cost_fraction"] for d in per_ds},
-            "wall_exhaustive_s": sum(d["wall_exhaustive_s"]
-                                     for d in per_ds.values()),
-            "wall_selfstop_s": sum(d["wall_selfstop_s"]
-                                   for d in per_ds.values()),
-            "wall_saved_s": sum(d["wall_exhaustive_s"]
-                                - d["wall_selfstop_s"]
-                                for d in per_ds.values()),
-            "gates": {k: bool(v) for k, v in gates.items()},
-            "wall_s": time.time() - wall0}, sort_keys=True) + "\n")
-
     for gate, ok in gates.items():
         print(f"gate,{gate},{ok}")
     bad = [g for g, ok in gates.items() if not ok]

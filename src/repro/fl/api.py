@@ -29,7 +29,8 @@ module makes that factoring literal:
 
 Every new strategy automatically inherits the DeltaBank / ``apply_rows`` /
 shard_map machinery — register it once and it runs under all three
-schedules, the benchmarks, and (stateless ones) the serving micro-batcher.
+schedules, the tuner's sweeps, and (stateless ones) the serving
+micro-batcher.
 
 The legacy class names (``AsyncSimulator``, ``BufferedAsyncSimulator``,
 ``SyncSimulator``) were removed in PR 10 after their one-release
@@ -195,7 +196,7 @@ _REGISTRY: Dict[str, Callable[..., Strategy]] = {}
 def register_strategy(*names):
     """Class decorator: ``@register_strategy("fedprox")`` makes the rule
     constructible as ``strategy("fedprox", **kw)`` everywhere — FLRun, the
-    benchmarks, and the serving micro-batcher."""
+    tuner's sweeps, and the serving micro-batcher."""
     def deco(factory):
         for nm in names:
             _REGISTRY[nm] = factory

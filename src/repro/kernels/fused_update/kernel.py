@@ -1,10 +1,9 @@
-"""Pallas TPU kernels: fused PersA-FL local updates and bank applies.
+"""Pallas TPU kernels: the server's fused delta applies.
 
-The paper's client loop applies η/λ-scaled parameter updates every local
-step; at multi-billion-parameter scale each unfused update costs 3–4 HBM
-round-trips (read w, read g, write w, plus the λ(θ−w) temporary for
-Option C).  This module fuses each chain into one read-modify-write pass,
-tiled as flat (block,) VMEM rows.  Math in f32, storage dtype preserved.
+Each apply is one read-modify-write pass over the parameters, tiled as
+flat VMEM rows.  Math in f32, storage dtype preserved.
+
+  * ``apply_scaled`` — one delta: ``w ← w − s·Δ`` with a traced scale.
 
 Two stacked-bank apply kernels close every aggregation window:
 
@@ -21,14 +20,12 @@ Two stacked-bank apply kernels close every aggregation window:
     operand block — identical padding-mask and pow2-row-bucket discipline,
     so one compile per bucket serves every window composition.
 
-Both have jnp oracles in ``ref.py`` (bit-comparable in interpret mode) and
+All three have jnp oracles in ``ref.py`` (bit-comparable in interpret mode) and
 pytree-aware jitted fronts in ``ops.py``.  Every entry takes ``interpret``
 with no default: ``ops.py`` is the one place that decides (the compiled
 kernel on a TPU, the Pallas interpreter elsewhere).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,62 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 64 * 1024  # 256 KiB f32 per operand per step — comfortably VMEM
-
-
-def _sgd_kernel(w_ref, g_ref, o_ref, *, eta):
-    w = w_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    o_ref[...] = (w - eta * g).astype(o_ref.dtype)
-
-
-def _prox_inner_kernel(t_ref, g_ref, w_ref, o_ref, *, eta_in, lam):
-    t = t_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    o_ref[...] = (t - eta_in * (g + lam * (t - w))).astype(o_ref.dtype)
-
-
-def _prox_outer_kernel(w_ref, t_ref, o_ref, *, eta, lam):
-    w = w_ref[...].astype(jnp.float32)
-    t = t_ref[...].astype(jnp.float32)
-    o_ref[...] = (w - eta * lam * (w - t)).astype(o_ref.dtype)
-
-
-def _run_flat(kernel, out_dtype, *arrays, interpret: bool):
-    """Pad to a BLOCK multiple, run the 1-D grid, unpad."""
-    flat = [a.reshape(-1) for a in arrays]
-    n = flat[0].shape[0]
-    pad = (-n) % BLOCK
-    if pad:
-        flat = [jnp.pad(a, (0, pad)) for a in flat]
-    total = n + pad
-    grid = (total // BLOCK,)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,)) for _ in flat],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((total,), out_dtype),
-        interpret=interpret,
-    )(*flat)
-    return out[:n].reshape(arrays[0].shape)
-
-
-def sgd_step(w, g, eta: float, *, interpret: bool):
-    return _run_flat(functools.partial(_sgd_kernel, eta=eta), w.dtype, w, g,
-                     interpret=interpret)
-
-
-def prox_inner(theta, g, w, eta_in: float, lam: float, *,
-               interpret: bool):
-    return _run_flat(functools.partial(_prox_inner_kernel, eta_in=eta_in,
-                                       lam=lam),
-                     theta.dtype, theta, g, w, interpret=interpret)
-
-
-def prox_outer(w, theta, eta: float, lam: float, *, interpret: bool):
-    return _run_flat(functools.partial(_prox_outer_kernel, eta=eta, lam=lam),
-                     w.dtype, w, theta, interpret=interpret)
 
 
 def _apply_scaled_kernel(w_ref, d_ref, s_ref, o_ref):

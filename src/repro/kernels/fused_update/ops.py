@@ -20,27 +20,6 @@ def _dispatch(kernel_fn, ref_fn, mode):
     return functools.partial(kernel_fn, interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("eta", "mode"))
-def sgd_step_tree(w_tree, g_tree, eta: float, mode: str = "auto"):
-    fn = _dispatch(K.sgd_step, R.sgd_step_ref, mode)
-    return jax.tree.map(lambda w, g: fn(w, g, eta), w_tree, g_tree)
-
-
-@functools.partial(jax.jit, static_argnames=("eta_in", "lam", "mode"))
-def prox_inner_tree(theta_tree, g_tree, w_tree, eta_in: float, lam: float,
-                    mode: str = "auto"):
-    fn = _dispatch(K.prox_inner, R.prox_inner_ref, mode)
-    return jax.tree.map(lambda t, g, w: fn(t, g, w, eta_in, lam),
-                        theta_tree, g_tree, w_tree)
-
-
-@functools.partial(jax.jit, static_argnames=("eta", "lam", "mode"))
-def prox_outer_tree(w_tree, theta_tree, eta: float, lam: float,
-                    mode: str = "auto"):
-    fn = _dispatch(K.prox_outer, R.prox_outer_ref, mode)
-    return jax.tree.map(lambda w, t: fn(w, t, eta, lam), w_tree, theta_tree)
-
-
 @functools.lru_cache(maxsize=None)
 def _apply_delta_jit():
     @functools.partial(jax.jit, static_argnames=("mode",), donate_argnums=0)

@@ -1,8 +1,5 @@
-"""Pure-jnp oracle for the fused local-update kernel.
+"""Pure-jnp oracles for the fused server-apply kernels.
 
-Option A step:   w ← w − η g
-Option C inner:  θ ← θ − η_in (g + λ(θ − w))
-Option C outer:  w ← w − η λ (w − θ)
 Server apply:    w ← w − s Δ   (s a *traced* scalar: β, β/M, or the
                  staleness-damped β/(1+τ)^a — no recompile per staleness)
 Stacked apply:   w ← w − Σ_i s_i Δ_i          (fp32 bank rows)
@@ -24,22 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-
-def sgd_step_ref(w, g, eta: float):
-    return (w.astype(jnp.float32) - eta * g.astype(jnp.float32)).astype(w.dtype)
-
-
-def prox_inner_ref(theta, g, w, eta_in: float, lam: float):
-    t32 = theta.astype(jnp.float32)
-    return (t32 - eta_in * (g.astype(jnp.float32)
-                            + lam * (t32 - w.astype(jnp.float32)))
-            ).astype(theta.dtype)
-
-
-def prox_outer_ref(w, theta, eta: float, lam: float):
-    w32 = w.astype(jnp.float32)
-    return (w32 - eta * lam * (w32 - theta.astype(jnp.float32))).astype(w.dtype)
 
 
 def apply_scaled_ref(w, d, scale):

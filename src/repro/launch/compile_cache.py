@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache: one helper decides.
 
 Every entry point (``chip_smoke.py``, ``launch/train.py``,
-``launch/serve.py``, ``benchmarks/run.py``) calls
+``launch/serve.py``, ``bench/run.py``) calls
 :func:`enable_compile_cache` before it compiles anything.
 
   * ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX reads it

@@ -1,8 +1,8 @@
 """The paper's experimental models (§5, Appendix D): small CNNs with
 pooling + dropout + cross-entropy for MNIST / CIFAR-10 classification.
 
-Pure-functional JAX; used by the FLRun event loop and the
-paper-reproduction benchmarks (Figure 2b/2c).
+Pure-functional JAX; used by the FLRun event loop, the examples and the
+sweeps that reproduce the paper's Figure 2 (``experiments/sweeps/``).
 """
 from __future__ import annotations
 

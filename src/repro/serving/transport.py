@@ -1020,7 +1020,7 @@ if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="loopback transport selftest (CI transport-smoke)")
+        description="loopback transport selftest")
     ap.add_argument("--clients", type=int, default=6)
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args()

@@ -388,3 +388,9 @@ def test_engine_client_fn_override_removed():
         CohortEngine(_pcfg(), _loss,
                      strategy=strategy("fedavg").bind(_pcfg(), _loss),
                      client_fn=lambda p, b: p)
+
+
+def test_quickstart_example_runs_on_the_current_api(run_example):
+    """``examples/quickstart.py`` at its smoke size, with deprecations
+    raised from the example or from ``repro`` fatal."""
+    run_example("quickstart.py", timeout=100)

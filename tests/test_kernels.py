@@ -131,41 +131,6 @@ def test_ssd_chunk_invariance():
 
 @pytest.mark.parametrize("shape", [(17,), (1000, 257), (3, 5, 7)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_sgd_step(shape, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    w = jax.random.normal(ks[0], shape, dtype)
-    g = jax.random.normal(ks[1], shape, dtype)
-    out = FK.sgd_step(w, g, 0.01, interpret=True)
-    ref = FR.sgd_step_ref(w, g, 0.01)
-    assert out.dtype == w.dtype
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=1e-6)
-
-
-def test_fused_prox_chain_matches_ref():
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    w = jax.random.normal(ks[0], (511,))
-    th = jax.random.normal(ks[1], (511,))
-    g = jax.random.normal(ks[2], (511,))
-    np.testing.assert_allclose(
-        np.asarray(FK.prox_inner(th, g, w, 0.02, 20.0, interpret=True)),
-        np.asarray(FR.prox_inner_ref(th, g, w, 0.02, 20.0)), atol=1e-6)
-    np.testing.assert_allclose(
-        np.asarray(FK.prox_outer(w, th, 0.01, 20.0, interpret=True)),
-        np.asarray(FR.prox_outer_ref(w, th, 0.01, 20.0)), atol=1e-6)
-
-
-def test_fused_update_tree_ops():
-    from repro.kernels.fused_update import ops
-    tree = {"a": jnp.ones((64,)), "b": {"c": jnp.full((8, 8), 2.0)}}
-    g = jax.tree.map(jnp.ones_like, tree)
-    out = ops.sgd_step_tree(tree, g, 0.5, mode="ref")
-    np.testing.assert_allclose(np.asarray(out["a"]), 0.5)
-    np.testing.assert_allclose(np.asarray(out["b"]["c"]), 1.5)
-
-
-@pytest.mark.parametrize("shape", [(17,), (1000, 257), (3, 5, 7)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_apply_scaled(shape, dtype):
     """The server-apply kernel (traced scale in SMEM) vs the jnp oracle."""
     ks = jax.random.split(jax.random.PRNGKey(2), 2)

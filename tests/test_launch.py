@@ -1,12 +1,13 @@
-"""Launch-layer tests: microbatching equivalence, specs, hlo_cost analyzer,
-roofline math, train/serve drivers at smoke scale."""
+"""Launch-layer tests: microbatching equivalence, the train steps, input
+specs, and the serve CLI at smoke scale."""
+import socket
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, get_shape, reduce_for_smoke
-from repro.launch import hlo_cost, roofline as rl
 from repro.launch.specs import (decode_specs, params_struct,
                                 prefill_batch_specs, train_batch_specs)
 from repro.launch.steps import make_loss, make_train_step, microbatched
@@ -95,58 +96,28 @@ def test_decode_specs_cache_struct():
     assert tok.shape == (128, 1) and pos.shape == ()
 
 
-def test_hlo_cost_counts_nested_trip_counts():
-    def f(x, w):
-        def outer(c, _):
-            def inner(cc, _):
-                return jnp.tanh(cc @ w), None
-            cc, _ = jax.lax.scan(inner, c, None, length=4)
-            return cc, None
-        c, _ = jax.lax.scan(outer, x, None, length=3)
-        return c
-    x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    compiled = jax.jit(f).lower(x, w).compile()
-    r = hlo_cost.analyze(compiled.as_text())
-    # dot flops dominate; the tanh adds 1 flop/element (~0.8%)
-    assert r["flops"] == pytest.approx(2 * 64 * 64 * 64 * 12, rel=2e-2)
+@pytest.mark.parametrize("flags", [
+    ("--personalize", "--requests", "4", "--tokens", "8"),
+    ("--listen", "0", "--serve-seconds", "2"),
+], ids=["personalize", "listen"])
+def test_serve_cli_smoke(run_cli, flags):
+    """``python -m repro.launch.serve`` at smoke width: a server-driven
+    personalized decode, and a boot of the socket front-end that serves
+    for two seconds and shuts down cleanly."""
+    run_cli(["-m", "repro.launch.serve", "--arch", "mamba2-130m", "--smoke",
+             *flags], timeout=70)
 
 
-def test_roofline_terms_math():
-    rec = {
-        "n_devices": 256,
-        "hlo_cost": {"flops": 197e12, "bytes": 819e9,
-                     "collective_bytes": {"all-reduce": 50e9,
-                                          "all-gather": 0,
-                                          "reduce-scatter": 0,
-                                          "all-to-all": 0,
-                                          "collective-permute": 0}},
-        "cost_analysis": {},
-        "collective_bytes": {},
-        "model_flops": 197e12 * 256,
-    }
-    r = rl.roofline_terms(rec)
-    assert r["compute_s"] == pytest.approx(1.0)
-    assert r["memory_s"] == pytest.approx(1.0)
-    assert r["collective_s"] == pytest.approx(1.0)
-    assert r["useful_ratio"] == pytest.approx(1.0)
-
-
-def test_grad_evals_accounting():
-    assert rl.grad_evals("A", 10, "full", 5) == 10
-    assert rl.grad_evals("B", 10, "fo", 5) == 20
-    assert rl.grad_evals("B", 10, "full", 5) == 40
-    assert rl.grad_evals("C", 10, "full", 5) == 60
-
-
-def test_collective_bytes_parser():
-    hlo = """
-ENTRY %main (p: f32[4]) -> f32[4] {
-  %p = f32[4]{0} parameter(0)
-  %ar = f32[4]{0} all-reduce(%p), replica_groups={}
-  ROOT %ag = f32[8]{0} all-gather(%ar), dimensions={0}
-}
-"""
-    got = rl.collective_bytes(hlo)
-    assert got["all-reduce"] == 16
-    assert got["all-gather"] == 32
+def test_serve_cli_boots_jax_distributed_on_a_2d_mesh(run_cli):
+    """The single-process ``jax.distributed`` bring-up (``--coordinator``,
+    ``--num-processes``, ``--process-id``) on eight forced host devices,
+    serving on the 2x4 ``("cohort", "model")`` mesh."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    out = run_cli(["-m", "repro.launch.serve", "--arch", "mamba2-130m",
+                   "--smoke", "--model-axis", "4", "--listen", "0",
+                   "--serve-seconds", "2", "--coordinator",
+                   f"127.0.0.1:{port}", "--num-processes", "1",
+                   "--process-id", "0"], timeout=45, devices=8)
+    assert "2-D mesh: cohort=2 × model=4 over 8 devices" in out

@@ -433,6 +433,54 @@ def test_server_subset_sharded_serving(cohort_impl):
     assert srv.stats["host_materializations"] == 0
 
 
+def test_head_only_rows_are_20x_smaller_than_full_rows():
+    """At 32 features a bias-only row is 33x smaller than a full-model
+    row (132 floats against 4), so a head-only ring holds at least 20x
+    the users in the same device memory."""
+    params = _params(d=32)
+    per_user = {}
+    for name, subset in (("full", None), ("head_only", ("b",))):
+        srv = PersonalizationServer(params, loss, _pcfg(),
+                                    personal_subset=subset)
+        for i in range(4):
+            srv.submit(f"u{i}", user_batch(i, d=32))
+        srv.flush()
+        per_user[name] = srv.stats["ring_bytes_per_user"]
+    assert per_user["full"] >= 20 * per_user["head_only"], per_user
+
+
+def test_head_only_personalization_tracks_full_model_accuracy():
+    """On the fig2 MNIST configuration (10 clients, 5 classes each), after
+    20 rounds of persafl option C, fine-tuning only the last FC layer
+    personalizes to within 0.1 accuracy of fine-tuning the whole model:
+    the head carries the personalization."""
+    from repro.configs.paper_models import MNIST_CNN
+    from repro.data.federated import make_federated_dataset
+    from repro.fl import DelayModel, FLRun, immediate, strategy
+    from repro.fl.evaluate import make_personalized_eval
+    from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
+
+    clients = make_federated_dataset("mnist", n_clients=10,
+                                     classes_per_client=5, seed=0)
+    cnn_l = lambda p, b: cnn_loss(MNIST_CNN, p, b, train=False)
+    cnn_a = lambda p, b: cnn_accuracy(MNIST_CNN, p, b)
+    pcfg = PersAFLConfig(option="C", q_local=5, lam=25.0, inner_steps=5,
+                         inner_eta=0.02, beta=1.0, eta=0.002)
+    sim = FLRun(clients=clients, loss_fn=cnn_l,
+                init_params=init_cnn(MNIST_CNN, jax.random.PRNGKey(0)),
+                pcfg=pcfg, delays=DelayModel(len(clients), seed=1),
+                strategy=strategy("persafl", option="C"),
+                schedule=immediate(), batch_size=16, seed=0)
+    sim.run(max_rounds=20)
+    ev_full = make_personalized_eval(cnn_l, cnn_a, clients, ft_steps=1,
+                                     ft_lr=0.01)
+    ev_head = make_personalized_eval(cnn_l, cnn_a, clients, ft_steps=1,
+                                     ft_lr=0.01, personal_subset="fc/#1")
+    a_full = float(ev_full(sim.state.params))
+    a_head = float(ev_head(sim.state.params))
+    assert abs(a_full - a_head) <= 0.1, (a_full, a_head)
+
+
 # -- personalized evaluation over a subset ----------------------------------
 
 def test_personalized_eval_subset_freezes_backbone():

@@ -13,6 +13,7 @@ bit-exact save/restore of quantized snapshots + residuals.
 """
 import asyncio
 import os
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -131,14 +132,15 @@ def test_apply_admitted_rows_dispatches_quant_stack():
                                np.asarray(s_f.params["w"]), atol=1e-6)
 
 
-@pytest.mark.skipif(jax.device_count() < 2,
-                    reason="needs >1 device for a sharded stack")
-def test_apply_admitted_rows_quant_sharded_stack():
-    """A QuantStack whose leaves span devices takes the ref path (Pallas
-    interpret can't trace through shard_map) and matches single-device."""
+_QUANT_SHARDED = textwrap.dedent("""
+    import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    rng = np.random.RandomState(4)
+    from repro.core import init_server_state
+    from repro.core.quant import QuantStack, quantize_stack
+    from repro.core.server import apply_admitted_rows
     ndev = jax.device_count()
+    assert ndev == 4, ndev
+    rng = np.random.RandomState(4)
     params = {"w": jnp.asarray(rng.randn(64).astype(np.float32))}
     stack = {"w": jnp.asarray(0.05 * rng.randn(ndev, 64)
                               .astype(np.float32))}
@@ -154,6 +156,16 @@ def test_apply_admitted_rows_quant_sharded_stack():
                                weights, ndev, 0)
     np.testing.assert_allclose(np.asarray(s_sh.params["w"]),
                                np.asarray(s_1d.params["w"]), atol=1e-6)
+    print("QUANT-SHARDED-OK")
+""")
+
+
+def test_apply_admitted_rows_quant_sharded_stack(run_cli):
+    """A QuantStack whose leaves span devices takes the ref path (Pallas
+    interpret can't trace through shard_map) and matches single-device.
+    Runs in a child process with four forced host devices."""
+    out = run_cli(["-c", _QUANT_SHARDED], timeout=30, devices=4)
+    assert "QUANT-SHARDED-OK" in out
 
 
 # ---------------------------------------------------------------------------
@@ -295,6 +307,22 @@ def test_wire_codec_int8_roundtrip_and_size():
     assert np.max(np.abs(dec8["x"] - tree["x"])) <= bound
 
 
+@pytest.mark.parametrize("body", ["submit", "head"])
+def test_wire_codec_int8_bodies_are_3p5x_smaller(body):
+    """Whole npz bodies, container included: a SUBMIT of a 32x256 batch
+    with int32 labels, and a HEAD of a 256x256 head with its bias, each
+    shrink at least 3.5x under ``codec="int8"``."""
+    rng = np.random.RandomState(10)
+    tree = ({"images": rng.randn(32, 256).astype(np.float32),
+             "labels": rng.randint(0, 256, 32).astype(np.int32)}
+            if body == "submit" else
+            {"w": rng.randn(256, 256).astype(np.float32),
+             "b": rng.randn(256).astype(np.float32)})
+    b32 = encode_pytree(tree, codec="fp32")
+    b8 = encode_pytree(tree, codec="int8")
+    assert len(b32) >= 3.5 * len(b8), (len(b32), len(b8))
+
+
 def test_wire_codec_rejects_unknown():
     with pytest.raises(ValueError):
         encode_pytree({"x": np.zeros(3, np.float32)}, codec="int4")
@@ -398,6 +426,42 @@ def test_quant_serving_residency_ratio():
     assert st["ring_bytes_per_user"] * 3.5 <= st["ring_bytes_per_user_fp32"]
     assert st["ring_bytes_saved_per_user"] == (
         st["ring_bytes_per_user_fp32"] - st["ring_bytes_per_user"])
+
+
+def test_int8_banking_keeps_fig2_personalized_accuracy():
+    """The fig2 MNIST configuration (16 clients, 5 classes each) driven
+    through an fp32-banking and an int8-with-error-feedback server for
+    six windows: personalized accuracy lands within 0.1, because error
+    feedback keeps the banking noise a residual, not a bias."""
+    from repro.configs.paper_models import MNIST_CNN
+    from repro.data.federated import make_federated_dataset
+    from repro.fl.evaluate import make_personalized_eval
+    from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
+
+    clients = make_federated_dataset("mnist", n_clients=16,
+                                     classes_per_client=5, seed=0)
+    params = init_cnn(MNIST_CNN, jax.random.PRNGKey(0))
+    cnn_l = lambda p, b: cnn_loss(MNIST_CNN, p, b, train=False)
+    cnn_a = lambda p, b: cnn_accuracy(MNIST_CNN, p, b)
+    pcfg = PersAFLConfig(option="C", lam=25.0, inner_steps=5,
+                         inner_eta=0.02, beta=1.0)
+    batches = [{"images": c.train_x[:16], "labels": c.train_y[:16]}
+               for c in clients]
+    ev = make_personalized_eval(cnn_l, cnn_a, clients, ft_steps=1,
+                                ft_lr=0.01)
+    acc = {}
+    for dtype in ("fp32", "int8"):
+        srv = PersonalizationServer(params, cnn_l, pcfg, modes=("C",),
+                                    max_pending=2 * len(clients),
+                                    delta_dtype=dtype)
+        for _ in range(6):
+            for u, b in enumerate(batches):
+                srv.submit(f"client{u}", b, mode="C")
+            srv.flush()
+            srv.advance_window()
+        acc[dtype] = float(ev(srv.params))
+    assert srv.stats["host_materializations"] == 0
+    assert abs(acc["fp32"] - acc["int8"]) <= 0.1, acc
 
 
 def test_quant_serving_head_and_stacked_heads():

@@ -3,6 +3,7 @@ heap-vs-vectorized event parity, the DeviceScheduler, and robust
 admission against adversarial rows."""
 import heapq
 import json
+import pathlib
 import types
 
 import jax
@@ -545,3 +546,64 @@ def test_delta_ring_robust_arg_validated():
     from repro.serving import DeltaRing
     with pytest.raises(ValueError):
         DeltaRing({"w": jnp.zeros(2)}, robust="median")
+
+
+@pytest.mark.parametrize("n", [1_000, 100_000])
+def test_scheduled_windows_apply_without_host_materializations(n):
+    """The fleet-scale window path: the DeviceScheduler forms each cohort
+    on device, adversaries corrupt rows of a synthetic delta bank, clip
+    admission weighs them, and one fused pass applies them. Every window
+    sees arrivals, no bank reaches the host, and the params stay finite."""
+    from repro.core import (apply_admitted_rows, init_server_state,
+                            robust_admission_weights)
+    from repro.fl.engine import DeltaBank
+    spec = ScenarioSpec(
+        n_clients=n, seed=0,
+        tiers=(Tier("fast", 0.5, 0.7), Tier("slow", 0.5, 1.6)),
+        diurnal=Diurnal(period=400.0, floor=0.25), dropout=0.02,
+        adversarial=Adversarial(frac=0.05, kinds=("scale", "sign_flip")))
+    model = spec.build()
+    sched = DeviceScheduler(model, window_len=30.0, cohort_cap=256,
+                            cycles_per_window=8)
+    cap, d = sched.cohort_cap, 1 << 12
+    state = init_server_state({"w": jnp.zeros(d, jnp.float32)})
+    base = {"w": 0.01 * jax.random.normal(jax.random.PRNGKey(0), (cap, d))}
+    bank_stats = {}
+    corrupted = 0
+    for _ in range(3):
+        ids, _ = sched.next_window()
+        fill = len(ids)
+        assert fill > 0
+        bank = DeltaBank(stacked=base, k=fill, stats=bank_stats)
+        fac = model.corruption_factors(ids)
+        vec = np.ones(cap, np.float32)
+        vec[:fill] = fac
+        corrupted += int(np.sum(fac != 1.0))
+        stacked = scale_rows(bank.stacked, vec)
+        weights, keep, _ = robust_admission_weights(
+            cap, [(j, 0) for j in range(fill)], bank_row_norms(stacked),
+            beta=0.1, count=fill, method="clip")
+        if not bool(keep.all()):
+            stacked = mask_rows(stacked, keep)
+        state = apply_admitted_rows(state, stacked, weights, fill,
+                                    staleness_max=0, staleness_sum=0.0)
+    assert sched.stats["arrivals"] > 0
+    assert corrupted > 0
+    assert bank_stats.get("host_materializations", 0) == 0
+    assert np.isfinite(np.asarray(state.params["w"])).all()
+
+
+def test_scenario_churn_example_runs_on_the_current_api(run_example):
+    """``examples/scenario_churn.py`` at its smoke size, with deprecations
+    raised from the example or from ``repro`` fatal."""
+    run_example("scenario_churn.py", timeout=90)
+
+
+def test_churn_robustness_sweep_smoke(run_cli):
+    """``experiments/sweeps/churn_robustness.py`` at ``SWEEP_FAST=1``: its
+    own gates (adversaries active, robust arms finite) raise on failure."""
+    sweep = (pathlib.Path(__file__).resolve().parents[1] / "experiments"
+             / "sweeps" / "churn_robustness.py")
+    out = run_cli([str(sweep)],
+                  timeout=150, env={"SWEEP_FAST": "1"})
+    assert "gate,adversaries_active,True" in out

@@ -138,3 +138,10 @@ def test_optimizers_descend():
             upd, state = opt.update(g, state, params)
             params = apply_updates(params, upd)
         assert float(loss(params)) < 0.1 * float(loss(w))
+
+
+def test_staleness_sweep_example_runs_on_the_current_api(run_example):
+    """``examples/staleness_sweep.py`` (the paper's Table 1 staleness
+    study) at its smoke size, with deprecations raised from the example
+    or from ``repro`` fatal."""
+    run_example("staleness_sweep.py", timeout=240)

@@ -136,6 +136,49 @@ def test_full_queue_flushes_synchronously_not_by_timer():
     asyncio.run(go())
 
 
+def test_concurrent_connections_coalesce_into_one_cohort_call():
+    """Sixteen connections, one user each, submit at once: their requests
+    share one cohort call, the wire keeps the micro-batching win, and
+    every head equals the in-process server's bit for bit."""
+    conns = 16
+    ref = _server(max_pending=conns)
+    tickets = [ref.submit(f"u{i}", user_batch(i)) for i in range(conns)]
+    expected = [ref.poll(t) for t in tickets]
+
+    async def go():
+        srv = _server(max_pending=conns)
+        ts = await TransportServer(srv, flush_ms=60_000.0,
+                                   max_inflight=4 * conns).start()
+        clients = [await AsyncTransportClient("127.0.0.1", ts.port)
+                   .connect() for _ in range(conns)]
+        tids = await asyncio.gather(*(
+            c.submit(f"u{i}", user_batch(i))
+            for i, c in enumerate(clients)))
+        heads = await asyncio.gather(*(
+            c.poll(t, wait_ms=30_000) for c, t in zip(clients, tids)))
+        stats = await clients[0].stats()
+        for c in clients:
+            await c.close()
+        await ts.stop()
+        return heads, stats, dict(ts.stats)
+
+    heads, stats, tstats = asyncio.run(go())
+    for got, want in zip(heads, expected):
+        _bitwise_equal(got, want)
+    assert stats["cohort_calls"] == 1
+    assert tstats["timer_flushes"] == 0
+    assert stats["host_materializations"] == 0
+
+
+def test_loopback_selftest_cli(run_cli):
+    """``python -m repro.serving.transport``: concurrent clients through
+    submit/poll/head over loopback, zero host materializations, clean
+    shutdown."""
+    out = run_cli(["-m", "repro.serving.transport", "--clients", "6",
+                   "--rounds", "3"], timeout=40)
+    assert "host_materializations=0,ok" in out
+
+
 def test_refusals_surface_typed_errors():
     """A request beyond tau_max polls back as code="dropped" over the
     wire (and a fairness refusal as code="capped")."""

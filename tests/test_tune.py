@@ -464,3 +464,10 @@ def test_report_and_winner_promotion(tmp_path):
     assert blob["winners"]["d/grid"]["strategy"] == \
         g["winner"]["strategy"]
     assert blob["note"] == "t"
+
+
+def test_run_tuned_example_replays_the_promoted_winners(run_example):
+    """``examples/run_tuned.py`` replays ``examples/tuned/fig2_winners.json``
+    at its smoke size, with deprecations raised from the example or from
+    ``repro`` fatal."""
+    run_example("run_tuned.py", timeout=240)
