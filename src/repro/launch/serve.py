@@ -5,8 +5,9 @@ each request is a *user* with their own token stream; the server coalesces
 all users' personalization (mode "B" one-step fine-tune or mode "C"
 Moreau-envelope prox solve) into one pow2-bucketed cohort call, and decode
 runs vmapped over the stacked per-user heads — no per-user Python loop on
-either side.  Prompt prefill is a single jitted ``lax.scan`` dispatch
-(prompt tokens advance on device); the decode loop proper stays
+either side.  Prompt prefill is a single jitted dispatch
+(``api.prefill``: a full-sequence forward that writes the cache, or a
+``lax.scan`` of the step); the decode loop proper stays
 step-by-step because each token depends on the previous argmax.
 
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-130m --smoke \
@@ -96,21 +97,10 @@ def _user_batch(cfg, seed: int, length: int):
 
 
 def make_prefill(cfg):
-    """Single-dispatch prompt prefill.
-
-    The prompt's first L−1 tokens only exist to warm the cache, so they
-    advance inside one jitted ``lax.scan`` instead of paying one Python
-    dispatch per token; the caller then decodes from the prompt's last
-    token.
-    """
+    """``api.prefill`` for ``cfg``, named so that it compiles as
+    ``jit_prefill`` (a jitted ``functools.partial`` is ``jit__unknown``)."""
     def prefill(params, cache, prompt):
-        def body(c, t):
-            tok = jax.lax.dynamic_slice_in_dim(prompt, t, 1, axis=1)
-            _, c = api.decode_step(cfg, params, c, tok, t)
-            return c, None
-        steps = jnp.arange(prompt.shape[1] - 1, dtype=jnp.int32)
-        cache, _ = jax.lax.scan(body, cache, steps)
-        return cache
+        return api.prefill(cfg, params, cache, prompt)
     return prefill
 
 
@@ -154,11 +144,11 @@ def _decode_programs(cfg, max_len, personal):
     shape (user count, prompt length).  The cast runs once a call, so
     the token step reads weights already in the compute dtype instead of
     casting them again at every token.  Cache init and the prefill take
-    the weights as given: the prefill's scan already hoists its casts
-    out of its loop, and on TPU v5e its loop rounds differently on a
-    pre-cast ``in_proj`` than on the float32 one, where the step is bit
-    for bit the same on either.  The call casts after the prefill, so
-    the cast and the prefill's own hoisted casts are never held at once.
+    the weights as given: the prefill casts them inside, as the
+    training forward does, and is kept off the cast view since a
+    pre-cast ``in_proj`` rounded the prefill differently on TPU v5e.
+    The call casts after the prefill, so the cast and the prefill's own
+    casts are never held at once.
 
     ``personal`` says which leaves carry the user axis: None for the
     shared params (nothing is vmapped), True for every leaf, or a
@@ -201,7 +191,7 @@ def _decode_shared(cfg, params, prompt, max_len, prompt_len):
         cast, init, prefill, step = _decode_programs(cfg, max_len, None)
         with span("decode.init"):
             cache = init(params, prompt)
-        with span("decode.prefill"):
+        with span("decode.prefill", tokens=prompt.shape[-1] - 1):
             cache = prefill(params, cache, prompt)
         with span("decode.cast"):
             view = cast(params)
@@ -234,7 +224,7 @@ def _decode_personalized(cfg, heads, prompt, max_len, prompt_len,
         cast, init, prefill, step = _decode_programs(cfg, max_len, personal)
         with span("decode.init"):
             cache = init(heads, prompt_u)
-        with span("decode.prefill"):
+        with span("decode.prefill", tokens=prompt.shape[-1] - 1):
             cache = prefill(heads, cache, prompt_u)
         with span("decode.cast"):
             view = cast(heads)

@@ -4,6 +4,7 @@ layer, tests and benchmarks.
     init_params(cfg, key)                 -> params pytree
     loss_fn(cfg, params, batch)           -> scalar loss  (the f_i of Eq. 2)
     init_cache(cfg, params?, batch, ...)  -> decode cache
+    prefill(cfg, params, cache, prompt)   -> cache after prompt[:, :-1]
     decode_step(cfg, params, cache, tokens, pos) -> (logits, cache)
     decode_weights(cfg, params)           -> params as decode computes with them
     make_train_batch_spec / make_decode_spec come from repro.launch.specs
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
@@ -61,6 +63,25 @@ def init_cache(cfg: ArchConfig, params, batch: Dict, max_len: int, dtype):
         return ed.init_encdec_cache(cfg, params, batch["frames"], max_len,
                                     dtype)
     return lm.init_lm_cache(cfg, B, max_len, dtype)
+
+
+def prefill(cfg: ArchConfig, params, cache, prompt):
+    """The decode cache after the prompt's first L-1 tokens (positions
+    0..L-2), from ``init_cache``'s: the caller decodes on from the last
+    token.  The ``ssm`` family and the hybrids run one full-sequence
+    forward that writes the cache (``ssm_lm.ssm_lm_prefill``), reading
+    each weight once; the other families step ``decode_step`` over the
+    tokens in one ``lax.scan``."""
+    if cfg.family in ("ssm", "hybrid"):
+        return ssm_lm.ssm_lm_prefill(cfg, params, cache, prompt[:, :-1])
+
+    def body(c, t):
+        tok = jax.lax.dynamic_slice_in_dim(prompt, t, 1, axis=1)
+        _, c = decode_step(cfg, params, c, tok, t)
+        return c, None
+    steps = jnp.arange(prompt.shape[1] - 1, dtype=jnp.int32)
+    cache, _ = jax.lax.scan(body, cache, steps)
+    return cache
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens, pos):

@@ -7,6 +7,9 @@ This is the pure-jnp reference; the Pallas TPU kernel lives in
 VMEM-tiled BlockSpecs.
 
 Decode path is the O(1)-per-token recurrence with a conv ring buffer.
+The prefill (``mamba2_prefill``) is the full-sequence path over a prompt,
+returning the decode cache it leaves: the last pre-conv rows and the
+chunked scan's final state.
 """
 from __future__ import annotations
 
@@ -76,11 +79,15 @@ def gated_norm(cfg: ArchConfig, y, z, gamma):
                     cfg.norm_eps).reshape(x.shape)
 
 
-def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int):
+def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int, precision=None):
     """Chunked SSD scan (pure jnp reference; f32 internals).
 
     x: (B,S,H,P); dt: (B,S,H) (post-softplus); a_log: (H,) (A = -exp(a_log));
-    B_mat/C_mat: (B,S,G,N) with H % G == 0.  Returns y: (B,S,H,P).
+    B_mat/C_mat: (B,S,G,N) with H % G == 0.  Returns y: (B,S,H,P) and the
+    f32 state after the last position, (B,H,P,N): the final carry of the
+    scan over chunks, which the decode step's recurrence continues from.
+    ``precision`` goes to the contractions; at the default a TPU rounds
+    their float32 inputs to bfloat16.
     """
     Bb, S, H, P = x.shape
     G, N = B_mat.shape[2], B_mat.shape[3]
@@ -105,11 +112,13 @@ def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int):
     # poisoning gradients through the where
     seg = jnp.where(tri[None, None, :, :, None], seg, -jnp.inf)
     L = jnp.exp(seg)
-    y_diag = jnp.einsum("bclhn,bcshn,bclsh,bcshp->bclhp", Cm, Bm, L, x_dt)
+    y_diag = jnp.einsum("bclhn,bcshn,bclsh,bcshp->bclhp", Cm, Bm, L, x_dt,
+                        precision=precision)
 
     # per-chunk terminal states
     decay_to_end = jnp.exp(a_cum[:, :, -1:, :] - a_cum)       # (B,nc,l,H)
-    chunk_states = jnp.einsum("bclhn,bclh,bclhp->bchpn", Bm, decay_to_end, x_dt)
+    chunk_states = jnp.einsum("bclhn,bclh,bclhp->bchpn", Bm, decay_to_end, x_dt,
+                              precision=precision)
     chunk_decay = jnp.exp(jnp.sum(a, axis=2))                 # (B,nc,H)
 
     def carry_fn(state, inp):
@@ -118,7 +127,7 @@ def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int):
         return new, state                                      # emit state *before* chunk
 
     init = jnp.zeros((Bb, H, P, N), f32)
-    _, prev_states = jax.lax.scan(
+    final, prev_states = jax.lax.scan(
         carry_fn, init,
         (jnp.moveaxis(chunk_states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
     prev_states = jnp.moveaxis(prev_states, 0, 1)             # (B,nc,H,P,N)
@@ -126,18 +135,45 @@ def ssd_chunked(x, dt, a_log, B_mat, C_mat, chunk: int):
     # inter-chunk contribution
     decay_from_start = jnp.exp(a_cum)                         # (B,nc,l,H)
     y_off = jnp.einsum("bclhn,bchpn,bclh->bclhp", Cm, prev_states,
-                       decay_from_start)
-    return (y_diag + y_off).reshape(Bb, S, H, P)
+                       decay_from_start, precision=precision)
+    return (y_diag + y_off).reshape(Bb, S, H, P), final
 
 
 def mamba2_forward(cfg: ArchConfig, p, x, *, use_kernel: bool = False):
     """Full-sequence Mamba2 block. x: (B,S,d) -> (B,S,d)."""
+    return _mamba2_sequence(cfg, p, x, use_kernel=use_kernel)[0]
+
+
+def mamba2_prefill(cfg: ArchConfig, p, x, n: int, cache):
+    """Full-sequence Mamba2 block over a prompt whose first ``n`` of S
+    positions are real (S a multiple of ``ssm.chunk``, the rest padding),
+    from the initial decode cache: returns (out (B,S,d), the decode cache
+    after position n - 1).  ``conv`` is the last ``conv_width - 1``
+    pre-conv rows of the real positions, behind the cache's zeros where
+    there are fewer; ``state`` the SSD scan's final state, exact because
+    padded positions take dt = 0 (they decay by exp(0) = 1 and add
+    nothing).  The SSD contracts at ``Precision.HIGHEST``: the state
+    every later decode step reads keeps float32, as the step's
+    elementwise recurrence does."""
+    valid = jnp.arange(x.shape[1]) < n
+    out, xbc, state = _mamba2_sequence(cfg, p, x, valid=valid,
+                                       precision=jax.lax.Precision.HIGHEST)
+    rows = jnp.concatenate([cache["conv"], xbc[:, :n].astype(
+        cache["conv"].dtype)], axis=1)
+    return out, {"conv": rows[:, n:], "state": state}
+
+
+def _mamba2_sequence(cfg: ArchConfig, p, x, *, use_kernel: bool = False,
+                     valid=None, precision=None):
+    """The full-sequence block: (out, pre-conv xbc rows, final SSD state;
+    None from the kernel).  ``valid`` (S,) zeroes dt where it is False;
+    ``precision`` goes to ``ssd_chunked``."""
     s = cfg.ssm
     d_inner, n_heads, _ = dims(cfg)
     dt_ = x.dtype
     proj = x @ p["in_proj"].astype(dt_)
-    z, xbc, dt_raw = _split_proj(cfg, proj)
-    xbc = _causal_conv(xbc, p["conv_w"], p["conv_b"])
+    z, xbc_in, dt_raw = _split_proj(cfg, proj)
+    xbc = _causal_conv(xbc_in, p["conv_w"], p["conv_b"])
     gn = s.n_groups * s.state_dim
     xs, Bm, Cm = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
     Bb, S = x.shape[:2]
@@ -146,17 +182,21 @@ def mamba2_forward(cfg: ArchConfig, p, x, *, use_kernel: bool = False):
     Cm = Cm.reshape(Bb, S, s.n_groups, s.state_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))
+    if valid is not None:
+        dt = jnp.where(valid[None, :, None], dt, 0.0)
     if use_kernel:
         from repro.kernels.ssd import ops as ssd_ops
-        y = ssd_ops.ssd(xs, dt, p["a_log"], Bm, Cm, chunk=s.chunk)
+        y, state = ssd_ops.ssd(xs, dt, p["a_log"], Bm, Cm,
+                               chunk=s.chunk), None
     else:
-        y = ssd_chunked(xs, dt, p["a_log"], Bm, Cm, chunk=s.chunk)
+        y, state = ssd_chunked(xs, dt, p["a_log"], Bm, Cm, chunk=s.chunk,
+                               precision=precision)
     y = y + p["d_skip"].astype(jnp.float32)[None, None, :, None] \
         * xs.astype(jnp.float32)
     y = y.reshape(Bb, S, d_inner).astype(dt_)
     y = shard_activation(y, "ssm_out")
     y = gated_norm(cfg, y, z, p["gate_norm"])
-    return y @ p["out_proj"].astype(dt_)
+    return y @ p["out_proj"].astype(dt_), xbc_in, state
 
 
 # ---------------------------------------------------------------------------

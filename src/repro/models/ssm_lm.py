@@ -20,10 +20,11 @@ the MLP's gate-up projection, ``gate_up(x) + (x @ A_i) @ B_i``.
 ``hybrid_table(cfg)`` gives each layer's invocation index, or -1 for a
 plain layer.  The forward is one scan over layers with the table as a
 scanned input and the invocation as a ``lax.cond``, so the lowered HLO
-holds one Mamba2 layer and one shared block; the decode step is unrolled
-over layers and indexes the table statically.  The hybrid's invocations
-and Mamba2 layers trace under ``spans.scope("zamba2.shared")`` and
-``spans.scope("zamba2.mamba")``.
+holds one Mamba2 layer and one shared block; the prefill
+(``ssm_lm_prefill``) is the same scan writing each layer's decode cache;
+the decode step is unrolled over layers and indexes the table
+statically.  The hybrid's invocations and Mamba2 layers trace under
+``spans.scope("zamba2.shared")`` and ``spans.scope("zamba2.mamba")``.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn
@@ -116,6 +118,16 @@ def _shared_block(cfg: ArchConfig, bp, ip, h, emb0, attend):
     return m @ ip["linear"].astype(dt), extra
 
 
+def _invocation(cfg: ArchConfig, params, inv):
+    """The shared block and the invocation weights of invocation ``inv``,
+    a traced index (the scans over layers)."""
+    bp = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+        w, inv % cfg.num_mem_blocks, keepdims=False), params["shared"])
+    ip = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+        w, inv, keepdims=False), params["hybrid"])
+    return bp, ip
+
+
 def _mixer_scope(cfg: ArchConfig):
     """The hybrid's Mamba2 layers trace under ``zamba2.mamba``; a pure
     SSM traces as it always has."""
@@ -131,19 +143,16 @@ def ssm_lm_hidden(cfg: ArchConfig, params, tokens, *, window: int = 0):
     B, S = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     use_kernel = False  # jnp reference on CPU; kernels validated separately
-    shared, hybrid = params.get("shared"), params.get("hybrid")
+    hybrid = params.get("hybrid")
 
     def attend(p, u):
         return attn.attn_forward(cfg, p, u, positions=positions,
                                  window=window)
 
     def invoke(inv, hh):
-        bp = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
-            w, inv % cfg.num_mem_blocks, keepdims=False), shared)
-        ip = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
-            w, inv, keepdims=False), hybrid)
         with scope("zamba2.shared"):
-            t, _ = _shared_block(cfg, bp, ip, hh, emb0, attend)
+            t, _ = _shared_block(cfg, *_invocation(cfg, params, inv), hh,
+                                 emb0, attend)
         return hh + t
 
     def body(h, xs):
@@ -218,6 +227,64 @@ def decode_weights(cfg: ArchConfig, params):
     def view(path, w):
         return w.astype(dt) if path[-1].key in _COMPUTE_LEAVES else w
     return jax.tree_util.tree_map_with_path(view, params)
+
+
+def ssm_lm_prefill(cfg: ArchConfig, params, cache, tokens):
+    """The decode cache after ``tokens`` (B, n) at positions 0..n-1, from
+    the initial cache: one full-sequence forward, a scan over layers that
+    emits each layer's Mamba2 cache (``mamba2_prefill``) and writes each
+    invocation's rotated K/V into its ring of ``T`` slots (position p at
+    slot p % T, the last ``T`` positions where n > T), as the decode step
+    would have left them.  The prompt is padded at the end to a multiple
+    of ``ssm.chunk``; padding never reaches a real position, since every
+    layer is causal.  No logits: the caller's first step computes them."""
+    n = tokens.shape[1]
+    if n == 0:
+        return cache
+    dt = cfg.activation_dtype
+    S = -(-n // cfg.ssm.chunk) * cfg.ssm.chunk
+    emb0 = embed_tokens(params["embed"], jnp.pad(tokens, ((0, 0), (0, S - n))),
+                        dt)
+    positions = jnp.broadcast_to(jnp.arange(S), emb0.shape[:2])
+    hybrid = params.get("hybrid")
+
+    def attend(p, u):
+        return attn.attn_forward(cfg, p, u, positions=positions,
+                                 window=cfg.sliding_window)
+
+    def invoke(inv, hh, ac):
+        with scope("zamba2.shared"):
+            t, kv = _shared_block(cfg, *_invocation(cfg, params, inv), hh,
+                                  emb0, attend)
+        T = ac["k"].shape[2]
+        written = np.arange(max(n - T, 0), n)          # static positions
+
+        def write(c, x):
+            ring = jax.lax.dynamic_index_in_dim(c, inv, keepdims=False)
+            ring = ring.at[:, written % T].set(x[:, written].astype(c.dtype))
+            return jax.lax.dynamic_update_index_in_dim(c, ring, inv, 0)
+        k, v = kv
+        return hh + t, {"k": write(ac["k"], k), "v": write(ac["v"], v)}
+
+    def body(carry, xs):
+        h, ac = carry
+        lp, inv, lc = xs
+        x = h
+        if hybrid is not None:
+            x, ac = jax.lax.cond(inv >= 0, lambda a: invoke(inv, h, a),
+                                 lambda a: (h, a), ac)
+        with _mixer_scope(cfg):
+            x = rms_norm(x, lp["ln"], cfg.norm_eps)
+            out, lc = m2.mamba2_prefill(cfg, lp["mamba"], x, n, lc)
+        return (h + out, ac), lc
+
+    table = jnp.asarray(hybrid_table(cfg), jnp.int32)
+    (_, ac), ssm = jax.lax.scan(body, (emb0, cache.get("attn")),
+                                (params["layers"], table, cache["ssm"]))
+    new_cache = {"ssm": ssm}
+    if ac is not None:
+        new_cache["attn"] = ac
+    return new_cache
 
 
 def ssm_lm_decode_step(cfg: ArchConfig, params, cache, tokens, pos):

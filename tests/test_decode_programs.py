@@ -38,8 +38,10 @@ def _prompt(users, prompt_len, seed=2):
 
 
 def _fresh_personalized(cfg, heads, prompt, max_len, prompt_len,
-                        params=None, spec=None):
-    """Personalized decode with its programs built inside the call."""
+                        params=None, spec=None, make_prefill=None):
+    """Personalized decode with its programs built inside the call
+    (``make_prefill(cfg)`` in place of ``serve.make_prefill``, where
+    given)."""
     if spec is not None:
         heads = merge_subset(params, heads)
         p_axes = jax.tree.map(lambda m: 0 if m else None, spec.mask(params))
@@ -49,7 +51,7 @@ def _fresh_personalized(cfg, heads, prompt, max_len, prompt_len,
     cache = jax.vmap(lambda p, t: api.init_cache(
         cfg, p, serve._init_batch(cfg, t[:, :1]), max_len,
         cfg.activation_dtype), in_axes=(p_axes, 0))(heads, prompt_u)
-    prefill = jax.jit(jax.vmap(serve.make_prefill(cfg),
+    prefill = jax.jit(jax.vmap((make_prefill or serve.make_prefill)(cfg),
                                in_axes=(p_axes, 0, 0)))
     step = jax.jit(jax.vmap(
         lambda p, c, t, pos: api.decode_step(cfg, p, c, t, pos),

@@ -9,8 +9,8 @@ built fresh on the float32 weights, under every user-axis layout; which
 leaves the view casts and which it leaves in float32; that the step
 program on the view holds no cast of a weight to bfloat16, where on the
 float32 weights it holds one per matrix; and the ``decode.cast`` span,
-once a call, after the prefill (which keeps the float32 weights) and
-before the first token step."""
+once a call, after the prefill (which takes the float32 weights and
+casts them inside) and before the first token step."""
 import dataclasses
 
 import jax

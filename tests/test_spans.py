@@ -26,17 +26,23 @@ from repro.spans import PREFIX
 SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
 
 
-def _trace_spans(tdir):
-    """(name without the prefix, start, end) of every program span."""
+def _span_events(tdir):
+    """(name without the prefix, start, end, metadata) of every program
+    span."""
     from jax.profiler import ProfileData
     path, = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
                       recursive=True)
     return [(ev.name[len(PREFIX):], ev.start_ns,
-             ev.start_ns + ev.duration_ns)
+             ev.start_ns + ev.duration_ns, dict(ev.stats))
             for plane in ProfileData.from_file(path).planes
             if plane.name.startswith("/host")
             for line in plane.lines for ev in line.events
             if ev.name.startswith(PREFIX)]
+
+
+def _trace_spans(tdir):
+    """(name without the prefix, start, end) of every program span."""
+    return [ev[:3] for ev in _span_events(tdir)]
 
 
 def _nesting(spans):
@@ -119,6 +125,10 @@ def test_decode_call_records_each_step(tmp_path):
                    ("decode", "decode.first_step"): 1,
                    ("decode", "decode.step"): gen_len - 1,
                    ("decode", "decode.collect"): 1}
+    # the prefill says how many positions it wrote: the prompt's first
+    # L - 1, once a call
+    assert [meta for name, _, _, meta in _span_events(str(tmp_path))
+            if name == "decode.prefill"] == [{"tokens": 3}]
 
 
 def _stack(k=4, d=6):
